@@ -24,8 +24,8 @@ number of programs.  Row *indices* pad with an out-of-range sentinel
 real row, which is what keeps the padded path bit-identical to the
 unpadded oracle.
 
-Quantization math is copied op-for-op from ``quantize._quantize_kernel``
-(reciprocal-mul, round-ties-to-even, clip) so
+Quantization math is :func:`repro.kernels.ref.quantize_int8`, the same
+the quantize kernel runs, so
 ``gather_quantize(table, rows) == quantize_int8(table[rows])`` holds
 bit-exactly — the row-independent codec property the sharded transports
 rely on survives the fusion.
@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from . import ref
 from .quantize import ROW_TILE, bucket_rows, pad_hidden, pad_rows
 
 
@@ -63,17 +64,6 @@ def _pad_idx(rows, n: int, sentinel: int) -> jax.Array:
 
 # -- gather + quantize --------------------------------------------------------
 
-def _quantize_math(x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """The shared per-row symmetric int8 encode — op-for-op the math of
-    ``quantize._quantize_kernel``, used by the Pallas body and the
-    jitted jnp fallback so both stay bit-identical to the oracle."""
-    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
-    scale = absmax * jnp.float32(1.0 / 127.0)
-    safe = jnp.where(scale > 0, scale, 1.0)
-    v = jnp.clip(jnp.round(x / safe), -127.0, 127.0).astype(jnp.int8)
-    return v, scale
-
-
 def _gather_quantize_kernel(tbl_ref, idx_ref, v_ref, s_ref):
     """One (ROW_TILE, Hp) output block: table gather fused with the
     per-row symmetric int8 encode.
@@ -84,9 +74,7 @@ def _gather_quantize_kernel(tbl_ref, idx_ref, v_ref, s_ref):
     # padded lanes carry index 0 (clamped): they quantize row 0 and are
     # sliced away by the caller — never scattered anywhere.
     x = jnp.take(tbl_ref[...], idx, axis=0)
-    v, scale = _quantize_math(x)
-    v_ref[...] = v
-    s_ref[...] = scale
+    v_ref[...], s_ref[...] = ref.quantize_int8(x)
 
 
 @jax.jit
@@ -94,7 +82,7 @@ def _gather_quantize_padded_jnp(table: jax.Array, idx: jax.Array
                                 ) -> tuple[jax.Array, jax.Array]:
     """Jitted jnp twin of the Pallas program: same bucket-padded shapes,
     same math — the fused device path off-TPU (ops dispatch)."""
-    return _quantize_math(jnp.take(table, idx[:, 0], axis=0))
+    return ref.quantize_int8(jnp.take(table, idx[:, 0], axis=0))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

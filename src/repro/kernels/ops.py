@@ -1,8 +1,11 @@
-"""Jit'd dispatchers over the Pallas kernels and their jnp oracles.
+"""Jit'd dispatchers over the Pallas kernels and their XLA twins.
 
-``use_pallas='auto'`` picks the Pallas path on TPU backends and the pure
-jnp oracle elsewhere; tests force ``use_pallas=True`` with interpret mode
-to validate the kernel bodies on CPU.
+``use_pallas='auto'`` picks the Pallas path on TPU backends only for the
+kernels the TPU compiler accepts (``_COMPILES_ON_TPU``), and the jnp /
+numpy paths everywhere else.  ``use_pallas=True`` always runs the Pallas
+body: in interpret mode off the chip (how the tests validate every
+kernel on CPU), compiled on a TPU, where a kernel outside
+``_COMPILES_ON_TPU`` raises the compiler's error.
 """
 
 from __future__ import annotations
@@ -18,20 +21,37 @@ from . import ref
 from . import swa_attention as _swa
 from . import topk_mask as _topk
 
+#: Kernels whose Pallas body compiles for TPU v5e (jax 0.9);
+#: tests/test_tpu_compile.py compiles each for a described v5e chip.  The
+#: Pallas TPU lowering refuses the rest, so "auto" never hands them to it:
+#:   gather_quantize, gnn_aggregate, dequant_aggregate (in-kernel
+#:     ``jnp.take``): "ValueError: Shape mismatch in input, indices and
+#:     output"
+#:   dequant_scatter (in-kernel ``.at[].set/add``): "NotImplementedError:
+#:     Unimplemented primitive in Pallas TPU lowering for KernelType.TC:
+#:     scatter"
+#:   topk_mask: "ValueError: Cannot store scalars to VMEM"
+#:   swa_attention_decode: "ValueError: The Pallas TPU lowering currently
+#:     requires that the last two dimensions of your block shape are
+#:     divisible by 8 and 128 respectively, or be equal to the respective
+#:     dimensions of the overall array"
+_COMPILES_ON_TPU = frozenset({"quantize_int8", "dequantize_int8"})
+
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _resolve(use_pallas) -> tuple[bool, bool]:
+def _resolve(use_pallas, kernel: str) -> tuple[bool, bool]:
     """→ (use_pallas, interpret)."""
+    on_tpu = _on_tpu()
     if use_pallas == "auto":
-        return (True, False) if _on_tpu() else (False, True)
-    return bool(use_pallas), not _on_tpu()
+        return on_tpu and kernel in _COMPILES_ON_TPU, not on_tpu
+    return bool(use_pallas), not on_tpu
 
 
 def gnn_aggregate(src_feats, ell_idx, ell_mask, *, use_pallas="auto"):
-    use, interp = _resolve(use_pallas)
+    use, interp = _resolve(use_pallas, "gnn_aggregate")
     if use:
         return _agg.gnn_aggregate(src_feats, ell_idx, ell_mask,
                                   interpret=interp)
@@ -40,7 +60,7 @@ def gnn_aggregate(src_feats, ell_idx, ell_mask, *, use_pallas="auto"):
 
 def swa_attention_decode(q, k, v, kv_pos, kv_valid, q_pos, *, window,
                          use_pallas="auto"):
-    use, interp = _resolve(use_pallas)
+    use, interp = _resolve(use_pallas, "swa_attention_decode")
     if use:
         return _swa.swa_attention_decode(q, k, v, kv_pos, kv_valid, q_pos,
                                          window=window, interpret=interp)
@@ -49,8 +69,9 @@ def swa_attention_decode(q, k, v, kv_pos, kv_valid, q_pos, *, window,
 
 
 def _np_quantize_int8(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """Numpy mirror of ref.quantize_int8, op-for-op (same fp32 ops in
-    the same order, round-half-even), so results stay bit-identical."""
+    """Numpy mirror of ref.quantize_int8: the plain fp32 math (correctly
+    rounded divide, round-half-even), which the device paths reproduce
+    bit for bit (``ref.rint_div``)."""
     x = np.asarray(x, np.float32)
     absmax = np.max(np.abs(x), axis=1, keepdims=True) \
         if x.size else np.zeros((x.shape[0], 1), np.float32)
@@ -72,7 +93,7 @@ def quantize_int8(x, *, use_pallas="auto"):
     calls this per push/pull with delta-sized (varying-shape) batches,
     where eager jnp pays ~ms dispatch per call and jit would retrace
     per shape (see ROADMAP: device-resident codec path)."""
-    use, interp = _resolve(use_pallas)
+    use, interp = _resolve(use_pallas, "quantize_int8")
     if use:
         return _quant.quantize_int8(x, interpret=interp)
     if isinstance(x, np.ndarray):
@@ -81,7 +102,7 @@ def quantize_int8(x, *, use_pallas="auto"):
 
 
 def dequantize_int8(values, scales, *, use_pallas="auto"):
-    use, interp = _resolve(use_pallas)
+    use, interp = _resolve(use_pallas, "dequantize_int8")
     if use:
         return _quant.dequantize_int8(values, scales, interpret=interp)
     if isinstance(values, np.ndarray):
@@ -115,9 +136,9 @@ def _np_dequant_scatter(table: np.ndarray, rows, values, scales, *,
 def gather_quantize(table, rows, *, use_pallas="auto"):
     """Fused row-gather + int8 encode (pull responses): bit-identical to
     ``quantize_int8(table[rows])``.  Numpy tables take the numpy fused
-    path; device tables run the jitted bucket-padded jnp twin off-TPU
-    and the Pallas kernel on TPU (interpret mode when forced on CPU)."""
-    use, interp = _resolve(use_pallas)
+    path; device tables run the jitted bucket-padded jnp twin on every
+    backend (the Pallas body only when forced, see ``_COMPILES_ON_TPU``)."""
+    use, interp = _resolve(use_pallas, "gather_quantize")
     if use:
         return _fused.gather_quantize(table, rows, interpret=interp)
     if isinstance(table, np.ndarray):
@@ -130,7 +151,7 @@ def dequant_scatter(table, rows, values, scales, *, accumulate=False,
     """Fused int8 decode + scatter-write/accumulate (push apply).
     Functional: returns the updated table; callers rebind.  Valid rows
     must be unique for ``accumulate=False``."""
-    use, interp = _resolve(use_pallas)
+    use, interp = _resolve(use_pallas, "dequant_scatter")
     if use:
         return _fused.dequant_scatter(table, rows, values, scales,
                                       accumulate=accumulate,
@@ -148,7 +169,7 @@ def dequant_aggregate(src_values, src_scales, ell_idx, ell_mask, *,
     ``gnn_aggregate(dequantize_int8(values, scales), idx, mask)``.  The
     non-Pallas path routes to the jnp oracle (not a numpy mirror) so the
     reduction order matches :func:`gnn_aggregate`'s dispatch exactly."""
-    use, interp = _resolve(use_pallas)
+    use, interp = _resolve(use_pallas, "dequant_aggregate")
     if use:
         return _agg.dequant_aggregate(src_values, src_scales, ell_idx,
                                       ell_mask, interpret=interp)
@@ -159,7 +180,7 @@ def dequant_aggregate(src_values, src_scales, ell_idx, ell_mask, *,
 
 
 def topk_mask(scores, k, *, use_pallas="auto"):
-    use, interp = _resolve(use_pallas)
+    use, interp = _resolve(use_pallas, "topk_mask")
     if use:
         return _topk.topk_mask(scores, k, interpret=interp)
     return ref.topk_mask(scores, k)

@@ -16,9 +16,11 @@ transports bit-identical to single-shard ones):
   q_ij    = clip(round(x_ij / scale_i), -127, 127)   int8
   x'_ij   = q_ij * scale_i
 
-Round-to-nearest (ties-to-even, matching jnp.round in the oracle) keeps
-the kernel deterministic, so encode(decode(encode(x))) is stable and
-Pallas-vs-ref parity is exact, not approximate.
+Round-to-nearest (ties-to-even, as ``np.rint``) keeps the kernel
+deterministic, so encode(decode(encode(x))) is stable.  The kernel runs
+the oracle's own math (:func:`repro.kernels.ref.quantize_int8`), whose
+rounding does not trust the TPU's divide (not correctly rounded), so
+Pallas, jnp and numpy agree bit for bit on the chip as well.
 
 Bucketed padding
 ----------------
@@ -53,6 +55,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from . import ref
 
 ROW_TILE = 256
 LANE = 128
@@ -124,15 +128,7 @@ def _quantize_kernel(x_ref, v_ref, s_ref):
     """One (ROW_TILE, H_padded) block: fused absmax + scale + round/clip.
 
     x_ref: (R, H) fp32; v_ref: (R, H) int8; s_ref: (R, 1) fp32."""
-    x = x_ref[...]
-    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)        # (R, 1)
-    # multiply by the fp32 reciprocal (not a divide): XLA folds /127 into
-    # a reciprocal-mul under jit but not in the eager oracle — writing the
-    # mul explicitly keeps kernel and oracle bit-identical.
-    scale = absmax * jnp.float32(1.0 / 127.0)
-    safe = jnp.where(scale > 0, scale, 1.0)
-    v_ref[...] = jnp.clip(jnp.round(x / safe), -127.0, 127.0).astype(jnp.int8)
-    s_ref[...] = scale
+    v_ref[...], s_ref[...] = ref.quantize_int8(x_ref[...])
 
 
 def _dequantize_kernel(v_ref, s_ref, out_ref):

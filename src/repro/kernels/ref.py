@@ -42,16 +42,59 @@ def swa_attention_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, H, dh)
 
 
+def _f32_bits_and(x: jax.Array, mask: int) -> jax.Array:
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(mask)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _round_about(a: jax.Array, s: jax.Array, m: jax.Array) -> jax.Array:
+    """``rint(fl(a / s))`` for fp32 ``a >= 0``, ``s > 0``, given an
+    integer ``m`` with ``|a/s - (m + 1/2)| < 1``.
+
+    ``b = m + 1/2`` is the only rounding boundary that close, and it is
+    an even fp32, so the correctly rounded quotient is ``b`` itself iff
+    ``a/s`` lies within half an ulp of ``b`` (ties go to ``b``), and
+    ``rint`` then rounds ``b`` half to even.  (Below ``b = 1/2`` the ulp
+    halves, but there both outcomes round to 0.)  The side is decided on
+    ``v = 2a - (2m+1)·s = 2s·(a/s - b)``, which is exact whenever it is
+    near the thresholds: ``s`` splits into two 12-bit halves, so both
+    partial products with the odd integer ``2m+1 <= 255`` are exact.
+    TPU fp32 add and multiply round correctly (only the divide does
+    not), so this holds on the chip too."""
+    two_b = 2.0 * m + 1.0
+    s_hi = _f32_bits_and(s, -4096)             # top 12 significand bits
+    v = (2.0 * a - two_b * s_hi) - two_b * (s - s_hi)
+    ulp = _f32_bits_and(0.5 * two_b, 0x7F800000) * jnp.float32(2.0 ** -23)
+    odd = m - 2.0 * jnp.floor(0.5 * m)
+    return jnp.where(v > ulp * s, m + 1.0,
+                     jnp.where(v < -ulp * s, m, m + odd))
+
+
+def rint_div(x: jax.Array, s: jax.Array) -> jax.Array:
+    """``np.rint(x / s)`` bit for bit, for fp32 ``x`` and ``s > 0`` with
+    ``|x / s| < 128``, on every backend.
+
+    The TPU's fp32 divide is not correctly rounded (up to 2 ulp off), so
+    ``round(x / s)`` on the chip disagrees with the host in a few values
+    per million.  Here the divide only locates the nearest half-integer
+    boundary, and :func:`_round_about` decides the side exactly."""
+    a = jnp.abs(x)
+    k = _round_about(a, s, jnp.floor(a / s))
+    return jnp.where(x < 0, -k, k)
+
+
 def quantize_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Per-row symmetric int8 quantization (wire codec, exchange subsystem).
 
     x: (n, hidden) fp32.  Returns (values int8 (n, hidden),
-    scales fp32 (n, 1)) with scale = row absmax / 127 (0 for zero rows)."""
-    absmax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=1, keepdims=True)
-    # reciprocal-mul, not divide — bit-identical to the Pallas kernel
+    scales fp32 (n, 1)) with scale = row absmax / 127 (0 for zero rows).
+    The Pallas kernels run this same math on their blocks."""
+    x = x.astype(jnp.float32)
+    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    # reciprocal-mul, not divide: what the numpy mirror computes
     scale = absmax * jnp.float32(1.0 / 127.0)
     safe = jnp.where(scale > 0, scale, 1.0)
-    q = jnp.clip(jnp.round(x / safe), -127.0, 127.0).astype(jnp.int8)
+    q = jnp.clip(rint_div(x, safe), -127.0, 127.0).astype(jnp.int8)
     return q, scale
 
 

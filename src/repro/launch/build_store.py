@@ -32,6 +32,8 @@ import time
 
 import numpy as np
 
+from repro.launch.chip import pin_cpu
+
 
 def _status_kb(field: str) -> float | None:
     try:
@@ -83,6 +85,7 @@ def _peak_rss_mb() -> float:
 
 
 def main(argv: list[str] | None = None) -> None:
+    pin_cpu()           # host-only build; the chip stays free
     ap = argparse.ArgumentParser(
         description="Build an mmap graph store (+ partition + shards)")
     ap.add_argument("--out", required=True, help="store directory")

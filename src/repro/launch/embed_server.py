@@ -39,6 +39,7 @@ import numpy as np
 from repro.core.embedding_server import EmbeddingServer
 from repro.exchange import wire
 from repro.exchange.codec import get_codec
+from repro.launch.chip import announce_device, enable_compile_cache, pin_cpu
 from repro.obsv import teleserve
 from repro.obsv.metrics import REGISTRY
 from repro.obsv.trace import TRACE
@@ -285,6 +286,11 @@ def main(argv: list[str] | None = None) -> None:
                          "and serve int8 gathers/writes through the "
                          "fused kernels (bit-identical values)")
     args = ap.parse_args(argv)
+    if args.device_tables:      # the tables live on the chip
+        enable_compile_cache()
+        announce_device("embed_server")
+    else:                       # host tables: keep off the chip
+        pin_cpu()
     serve(args.num_layers, args.hidden, host=args.host, port=args.port,
           device_tables=args.device_tables)
 

@@ -31,10 +31,12 @@ import time
 
 from repro.fedsvc.coordinator import serve_in_thread
 from repro.fedsvc.runtime import RunConfig, make_coordinator_state
+from repro.launch.chip import pin_cpu
 from repro.obsv.trace import TRACE
 
 
 def main(argv: list[str] | None = None) -> None:
+    pin_cpu()           # evaluation runs on the CPU; the chip is a worker's
     ap = argparse.ArgumentParser(
         description="Federated weight-aggregation coordinator "
                     "(repro.fedsvc protocol)")

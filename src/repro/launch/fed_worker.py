@@ -33,6 +33,7 @@ import json
 
 from repro.fedsvc.runtime import RunConfig
 from repro.fedsvc.worker import FedWorker, WorkerScenario
+from repro.launch.chip import announce_device, enable_compile_cache
 from repro.obsv import teleserve
 from repro.obsv.trace import TRACE
 
@@ -61,6 +62,8 @@ def main(argv: list[str] | None = None) -> None:
                          "clients with no port of their own")
     RunConfig.add_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    announce_device("fed_worker")
 
     cfg = RunConfig.from_args(args)
     client_ids = [int(c) for c in args.client_ids.split(",") if c != ""]

@@ -24,6 +24,7 @@ import time
 from repro.fedsvc.runtime import RunConfig
 from repro.gnnserve import build_serving
 from repro.gnnserve.frontend import serve_in_thread
+from repro.launch.chip import announce_device, enable_compile_cache
 from repro.obsv.trace import TRACE
 
 
@@ -62,6 +63,8 @@ def main(argv: list[str] | None = None) -> None:
                          "ending at num-layers (default 1,..,L)")
     RunConfig.add_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    announce_device("gnn_serve")
 
     cfg = RunConfig.from_args(args)
     sched = None

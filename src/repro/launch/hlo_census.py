@@ -322,13 +322,3 @@ def census(hlo: str) -> dict:
     return {"flops": flops, "hbm_bytes": hbm,
             "collective_bytes": coll,
             "collective_total": sum(coll.values())}
-
-
-def compiled_flops(compiled) -> float:
-    """``cost_analysis()['flops']`` across jax versions: 0.4.x returns a
-    list of per-program dicts, >=0.5 a single dict; either may omit the
-    key for trivial programs."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return float(ca.get("flops", 0.0))

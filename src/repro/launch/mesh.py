@@ -7,34 +7,7 @@ first two lines) should build the production meshes.
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-from jax.sharding import AbstractMesh
-
-
-def make_abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Version-tolerant AbstractMesh constructor.
-
-    jax <= 0.4.x takes a single ``((name, size), ...)`` shape tuple;
-    jax >= 0.5 takes ``(axis_sizes, axis_names)`` positionally.  Tests
-    and dry-runs build abstract meshes on 1 CPU device, so this is the
-    one choke point for that API drift (see tests/test_distributed.py).
-    """
-    params = list(inspect.signature(AbstractMesh.__init__).parameters)
-    if "shape_tuple" in params:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    return AbstractMesh(tuple(shape), tuple(axes))
-
-
-def _make_device_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """``jax.make_mesh`` with a fallback for jax builds that predate it
-    (same positional ``(axis_shapes, axis_names)`` order either way)."""
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    import numpy as np
-    devs = np.asarray(jax.devices()).reshape(shape)
-    return jax.sharding.Mesh(devs, axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -42,13 +15,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods × 256 chips as (pod=2, data=16, model=16)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_device_mesh(shape, axes)
+    return jax.make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist locally (tests / examples): (data=N, model=1)."""
     n = len(jax.devices())
-    return _make_device_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"))
 
 
 # TPU v5e hardware constants (per chip) for the roofline terms.

@@ -33,6 +33,7 @@ import argparse
 import json
 import sys
 
+from repro.launch.chip import pin_cpu
 from repro.obsv import teleserve
 
 
@@ -63,6 +64,7 @@ def dump(endpoints: list[tuple[str, object]]) -> tuple[dict, str]:
 
 
 def main(argv: list[str] | None = None) -> None:
+    pin_cpu()           # a scraper never needs the chip
     ap = argparse.ArgumentParser(
         description="Scrape OP_METRICS/OP_TRACE from every endpoint of "
                     "a deployment; merge into one Chrome trace + one "

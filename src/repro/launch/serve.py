@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import get_config, get_reduced, list_archs
 from repro.data import synthetic_request_stream
+from repro.launch.chip import announce_device, enable_compile_cache
 from repro.models import lm
 
 
@@ -31,6 +32,8 @@ def main():
     ap.add_argument("--generate", type=int, default=32)
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
+    dev = announce_device("serve")
 
     cfg = get_config(args.arch) if args.full_config \
         else get_reduced(args.arch)
@@ -58,7 +61,7 @@ def main():
     dt = time.perf_counter() - t0
     n_tok = args.batch * (args.prompt + args.generate - 1)
     print(f"arch={cfg.name} served {n_tok} tokens in {dt:.2f}s "
-          f"({n_tok / dt:.1f} tok/s on CPU)")
+          f"({n_tok / dt:.1f} tok/s on {dev['kind']})")
     gen = np.stack(generated, axis=1)
     print("sample generations (token ids):")
     for row in gen[: min(2, args.batch)]:

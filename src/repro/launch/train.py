@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import get_config, get_reduced, list_archs
 from repro.data import synthetic_batches
+from repro.launch.chip import announce_device, enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_optimizer
 from repro.models import lm
@@ -36,6 +37,8 @@ def main():
                     help="use the published config (needs real hardware)")
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args()
+    enable_compile_cache()
+    announce_device("train")
 
     cfg = get_config(args.arch) if args.full_config \
         else get_reduced(args.arch)

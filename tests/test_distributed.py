@@ -7,11 +7,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config, get_reduced
 from repro.distributed import sharding as sh
-from repro.launch.mesh import make_abstract_mesh
 from repro.models import lm
 from repro.optim import adafactor, adamw
 
@@ -19,7 +18,7 @@ from repro.optim import adafactor, adamw
 def fake_mesh(multi_pod=False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_abstract_mesh(shape, axes)
+    return AbstractMesh(shape, axes)
 
 
 @pytest.mark.parametrize("arch", ["nemotron-4-340b", "phi3.5-moe-42b-a6.6b",
@@ -178,10 +177,10 @@ def test_census_counts_scan_trips():
 
 
 def test_census_matches_cost_analysis_loop_free():
-    from repro.launch.hlo_census import census, compiled_flops
+    from repro.launch.hlo_census import census
     x = jnp.ones((32, 64))
     w = jnp.ones((64, 128))
     c = jax.jit(lambda a, b: a @ b).lower(x, w).compile()
     cen = census(c.as_text())
-    ca = compiled_flops(c)
+    ca = c.cost_analysis()["flops"]
     assert abs(cen["flops"] - ca) / ca < 0.05

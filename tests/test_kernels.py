@@ -262,6 +262,52 @@ def test_dequant_aggregate_matches_two_step(n_src, n_dst, k, h):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+# -- int8 rounding: exact on every backend -----------------------------------
+
+def _ulp_walk(x: np.ndarray, steps: int) -> np.ndarray:
+    """x and its ``steps`` fp32 neighbours on either side."""
+    out = [x]
+    up, down = x.copy(), x.copy()
+    for _ in range(steps):
+        up = np.nextafter(up, np.float32(np.inf))
+        down = np.nextafter(down, np.float32(-np.inf))
+        out += [up, down]
+    return np.concatenate(out)
+
+
+def test_rint_div_matches_numpy_at_rounding_boundaries():
+    """Quotients at and a few ulps around every half-integer boundary
+    (incl. exact ties and double-rounding cases), both signs: the exact
+    side decision reproduces numpy's correctly rounded divide + rint."""
+    rng = np.random.default_rng(0)
+    s = np.concatenate([rng.uniform(1e-4, 10.0, 3000),
+                        2.0 ** rng.integers(-8, 4, 200),      # exact ties
+                        np.full(64, 0.75)]).astype(np.float32)
+    b = (rng.integers(0, 128, s.size) + 0.5).astype(np.float32)
+    x = _ulp_walk((b * s).astype(np.float32), 4)
+    x = np.concatenate([x, -x])
+    ss = np.tile(s, 18)
+    want = np.rint(x / ss)
+    got = np.asarray(ref.rint_div(jnp.asarray(x), jnp.asarray(ss)))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_round_about_tolerates_an_inexact_divide():
+    """A divide up to a few ulps off (the TPU's) can put ``floor(a/s)``
+    on either side of an integer quotient; both candidates must round
+    the same as numpy."""
+    rng = np.random.default_rng(1)
+    s = rng.uniform(1e-4, 10.0, 2000).astype(np.float32)
+    k = rng.integers(1, 128, s.size).astype(np.float32)
+    a = _ulp_walk((k * s).astype(np.float32), 3)
+    ss, kk = np.tile(s, 7), np.tile(k, 7)
+    want = np.rint(a / ss)
+    for m in (kk - 1.0, kk):
+        got = ref._round_about(jnp.asarray(a), jnp.asarray(ss),
+                               jnp.asarray(m))
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
 # -- bucketed padding: retrace guard + boundary bit-identity ------------------
 
 def test_bucketed_quantize_retrace_guard():
