@@ -27,7 +27,7 @@ from typing import Optional
 from .core import Finding, SourceFile, dotted_name
 
 _METRIC_METHODS = {"counter", "gauge", "histogram"}
-_SPAN_METHODS = {"span", "instant"}
+_SPAN_METHODS = {"span", "complete"}
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)+$")
 
 

@@ -43,7 +43,7 @@ from repro.graphs.partition import (ClientShard, bfs_partition,
                                     make_client_shards)
 from repro.graphs.sampler import NeighborSampler
 from repro.models import gnn
-from repro.obsv.trace import TRACE
+from repro.obsv.trace import TRACE, install_jax_hooks
 from repro.optim import Optimizer, adam
 
 from .cost_model import NetworkModel
@@ -226,6 +226,7 @@ class FederatedGNNTrainer:
             else:
                 part = bfs_partition(graph, num_clients, seed=seed)
         self.part = part
+        install_jax_hooks()
         self._setup()
 
     # -- setup ----------------------------------------------------------------
@@ -679,8 +680,9 @@ class FederatedGNNTrainer:
         # pre-sample the round's minibatches (sampling is part of the
         # measured train phase, like DGL's dataloader)
         t0 = time.perf_counter()
-        epochs_batches = [list(self.samplers[ci].epoch())
-                          for _ in range(self.epochs)]
+        with TRACE.span("client.sample", args={"client": ci}):
+            epochs_batches = [list(self.samplers[ci].epoch())
+                              for _ in range(self.epochs)]
         sample_t = time.perf_counter() - t0
         p.pull, p.dynamic_pull, sizes = self._pull_time(
             ci, [mb for ep in epochs_batches for mb in ep])
@@ -695,10 +697,12 @@ class FederatedGNNTrainer:
             with TRACE.span("client.train_epoch",
                             args={"client": ci, "epoch": e}):
                 for mb in batches:
-                    batch = gnn.blocks_to_arrays(mb)
-                    params, opt_state, loss = self._train_step(
-                        params, opt_state, batch, self.feats[ci],
-                        self._caches[ci], self.labels[ci])
+                    with TRACE.span("client.step_inputs"):
+                        batch = gnn.blocks_to_arrays(mb)
+                    with TRACE.span("client.step_dispatch"):
+                        params, opt_state, loss = self._train_step(
+                            params, opt_state, batch, self.feats[ci],
+                            self._caches[ci], self.labels[ci])
                 jax.block_until_ready(loss)
             t_train += time.perf_counter() - t0
             if st.overlap_push and e == self.epochs - 1:
@@ -746,9 +750,10 @@ class FederatedGNNTrainer:
 
         # all clients pulled before anyone pushes (server is static
         # within the round) — apply the planned pushes now.
-        for res in results:
-            if res.push_plan is not None:
-                self.ex_clients[res.client_id].apply_push(res.push_plan)
+        with TRACE.span("round.apply_push"):
+            for res in results:
+                if res.push_plan is not None:
+                    self.ex_clients[res.client_id].apply_push(res.push_plan)
 
         # FedAvg + validation on the aggregation server.  The leaf-wise
         # fedavg_leaves is shared with the fedsvc coordinator, so the

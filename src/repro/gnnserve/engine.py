@@ -39,6 +39,7 @@ import numpy as np
 from repro.graphs.sampler import Block, _pad_to, _round_up
 from repro.models import gnn
 from repro.obsv.metrics import REGISTRY
+from repro.obsv.trace import install_jax_hooks
 
 _FORWARDS = REGISTRY.counter("gnnserve.forwards")
 
@@ -73,6 +74,7 @@ class ShardServeEngine:
     def __init__(self, params, shard, *, conv: str, cache: HotEmbeddingCache,
                  serve_fanout: int = 10, batch_size: int = 64,
                  depth_schedule: list[int] | None = None):
+        install_jax_hooks()
         self.params = params
         self.shard = shard
         self.conv = conv

@@ -5,7 +5,7 @@ Usage::
     from repro.obsv import trace
 
     with trace.TRACE.span("client.train", args={"client": ci}):
-        ...                      # or @trace.traced("client.train")
+        ...
 
 Spans are complete-events: name, category, thread id, start and
 duration on the ``time.perf_counter`` clock, plus optional args merged
@@ -21,6 +21,13 @@ so instrumentation can stay in hot paths permanently.  Enable with
 non-empty value ≠ "0"), which is how the launch CLIs turn tracing on in
 child processes.
 
+Profiler bridge: while enabled, every span also enters
+``jax.profiler.TraceAnnotation(name)`` (taken only if ``jax`` is
+already imported), so a ``jax.profiler`` session records it on the
+profiler's own clock beside the device ops.  :func:`install_jax_hooks`
+adds the ``jit.compile`` span and the ``jit.compiles`` counter from
+JAX's compile events.  This module itself never imports jax.
+
 Export is Chrome trace-event JSON (the Perfetto / ``chrome://tracing``
 format): ``ph:"X"`` duration events with microsecond timestamps, plus
 ``process_name`` metadata so every process of a federated deployment
@@ -34,12 +41,13 @@ so raw timestamps are only comparable after alignment).
 from __future__ import annotations
 
 import collections
-import functools
-import json
 import os
+import sys
 import threading
 import time
 from typing import Optional
+
+from .metrics import REGISTRY
 
 _perf = time.perf_counter
 
@@ -58,9 +66,26 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation(name)``, or ``None``
+    where jax has not been imported."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        _annotation_cls = getattr(getattr(jax, "profiler", None),
+                                  "TraceAnnotation", None)
+        if _annotation_cls is None:
+            return None
+    ann = _annotation_cls(name)
+    ann.__enter__()
+    return ann
+
 
 class _Span:
-    __slots__ = ("_rec", "name", "cat", "args", "_t0")
+    __slots__ = ("_rec", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, cat: str,
                  args: Optional[dict]):
@@ -70,17 +95,15 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self._ann = _annotation(self.name)
         self._t0 = _perf()
         return self
 
     def __exit__(self, *exc):
         t0 = self._t0
-        rec = self._rec
-        args = self.args
-        if rec.context:
-            args = {**rec.context, **(args or {})}
-        rec.events.append((self.name, self.cat,
-                           threading.get_ident(), t0, _perf() - t0, args))
+        self._rec.complete(self.name, t0, _perf() - t0, self.cat, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return False
 
 
@@ -133,15 +156,14 @@ class TraceRecorder:
             return NOOP_SPAN
         return _Span(self, name, cat, args)
 
-    def instant(self, name: str, cat: str = "",
-                args: Optional[dict] = None) -> None:
-        """Zero-duration marker event."""
-        if not self.enabled:
-            return
+    def complete(self, name: str, t0: float, dur: float, cat: str = "",
+                 args: Optional[dict] = None) -> None:
+        """Record a span that has already happened: ``t0`` and ``dur``
+        in seconds on the ``perf_counter`` clock.  Records even when
+        disabled: callers check :attr:`enabled` first."""
         if self.context:
             args = {**self.context, **(args or {})}
-        self.events.append((name, cat, threading.get_ident(),
-                            _perf(), 0.0, args))
+        self.events.append((name, cat, threading.get_ident(), t0, dur, args))
 
     # -- export ------------------------------------------------------------
 
@@ -160,27 +182,6 @@ class TraceRecorder:
         """This recorder's events in Chrome trace-event form."""
         return _snapshot_to_chrome(self.snapshot(), offset_s=offset_s,
                                    pid=pid)
-
-    def write_chrome_trace(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump({"traceEvents": self.chrome_events(),
-                       "displayTimeUnit": "ms"}, f)
-
-
-def traced(name: str, cat: str = ""):
-    """Decorator form of :meth:`TraceRecorder.span` on the global
-    recorder; disabled overhead is one attribute check per call."""
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not TRACE.enabled:
-                return fn(*a, **kw)
-            # bounded: `name` is the decorator's literal argument, fixed
-            # per decorated function  # repro-lint: disable=TL001
-            with TRACE.span(name, cat):
-                return fn(*a, **kw)
-        return wrapper
-    return deco
 
 
 # -- cross-process merge ------------------------------------------------------
@@ -236,5 +237,45 @@ if os.environ.get("REPRO_TRACE", "0") not in ("", "0"):
     TRACE.enable()
 
 
-def get_recorder() -> TraceRecorder:
-    return TRACE
+# -- JAX compile events -------------------------------------------------------
+
+#: JAX's event around each backend compile (or persistent-cache load)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILES = REGISTRY.counter("jit.compiles")
+_open_compiles = threading.local()
+_hooks_lock = threading.Lock()
+_hooks_installed = False
+
+
+def _on_compile_start(event: str, value, **_) -> None:
+    # JAX records the compile's start as a scalar on the same event
+    if event != COMPILE_EVENT or not TRACE.enabled:
+        return
+    ann = _annotation("jit.compile")
+    if ann is not None:
+        _open_compiles.__dict__.setdefault("stack", []).append(ann)
+
+
+def _on_compile_end(event: str, duration: float, **kw) -> None:
+    if event != COMPILE_EVENT:
+        return
+    _COMPILES.inc()
+    stack = getattr(_open_compiles, "stack", None)
+    if stack:
+        stack.pop().__exit__(None, None, None)
+    if TRACE.enabled:
+        TRACE.complete("jit.compile", _perf() - duration, duration,
+                       args={"fun": kw.get("fun_name", "")})
+
+
+def install_jax_hooks() -> None:
+    """Count every XLA compile in ``jit.compiles`` and, while tracing,
+    record it as a ``jit.compile`` span.  Idempotent; imports jax."""
+    global _hooks_installed
+    from jax import monitoring
+    with _hooks_lock:
+        if _hooks_installed:
+            return
+        monitoring.register_scalar_listener(_on_compile_start)
+        monitoring.register_event_duration_secs_listener(_on_compile_end)
+        _hooks_installed = True

@@ -7,6 +7,10 @@ Gated invariants:
     snapshot/delta arithmetic, text exposition
   * disabled tracing is a true no-op: the shared noop span object, zero
     recorded events, ring capacity bounded when enabled
+  * a traced federated round records its host-work spans (sampling,
+    step inputs, step dispatch, push commit, compiles) and the step
+    spans sum to no more than their epoch; an untraced round records
+    nothing; every compile bumps ``jit.compiles``
   * Chrome trace-event export is valid and merging is deterministic —
     same snapshots in, byte-identical JSON out, distinct synthetic pids
     even for same-OS-process sources
@@ -38,8 +42,7 @@ from repro.launch.embed_server import serve_in_thread as embed_serve
 from repro.obsv import teleserve, trace
 from repro.obsv.metrics import (REGISTRY, Histogram, MetricsRegistry,
                                 SampleWindow, log_bounds)
-from repro.obsv.trace import (NOOP_SPAN, TraceRecorder, merge_snapshots,
-                              traced)
+from repro.obsv.trace import NOOP_SPAN, TraceRecorder, merge_snapshots
 
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
@@ -182,7 +185,6 @@ def test_disabled_span_is_shared_noop_and_records_nothing():
     assert rec.span("y", args={"k": 1}) is NOOP_SPAN
     with rec.span("z"):
         pass
-    rec.instant("i")
     assert len(rec.events) == 0
 
 
@@ -214,18 +216,109 @@ def test_ring_buffer_bounded():
     assert rec.events[0][0] == "s92"        # oldest dropped
 
 
-def test_traced_decorator():
-    trace.TRACE.enable()
+# -- spans of a federated round ---------------------------------------------
 
-    @traced("fn.work")
-    def work(x):
-        return x + 1
+#: spans the trainer records around its untimed host work, and the
+#: compile span from JAX's compile events
+ROUND_HOST_SPANS = ("client.sample", "client.step_inputs",
+                    "client.step_dispatch", "round.apply_push", "jit.compile")
 
-    assert work(1) == 2
-    assert [e[0] for e in trace.TRACE.events] == ["fn.work"]
+
+@pytest.fixture(scope="module")
+def traced_round():
+    """A tiny OP trainer (int8 with error feedback): its first round
+    traced (so it compiles inside the trace), then a second one with
+    tracing off.  Returns both rounds' recorded events and the first
+    round's wall seconds."""
+    import dataclasses
+
+    from repro.core import FederatedGNNTrainer, default_strategies
+    from repro.graphs import make_graph
+    g = make_graph("reddit", scale=0.05, seed=3)
+    st = dataclasses.replace(default_strategies()["OP"], codec="int8",
+                             error_feedback=True)
+    tr = FederatedGNNTrainer(g, 2, st, batch_size=16, seed=0,
+                             epochs_per_round=2)
+    tr.pretrain_round()
     trace.TRACE.disable()
-    assert work(2) == 3
-    assert len(trace.TRACE.events) == 1     # disabled call recorded nothing
+    trace.TRACE.clear()
+    trace.TRACE.enable()
+    t0 = time.perf_counter()
+    tr.run_round(0, 0.0)
+    wall = time.perf_counter() - t0
+    trace.TRACE.disable()
+    on = list(trace.TRACE.events)
+    trace.TRACE.clear()
+    tr.run_round(1, 0.0)
+    off = list(trace.TRACE.events)
+    trace.TRACE.context.clear()
+    return {"on": on, "off": off, "wall": wall, "clients": tr.k,
+            "epochs": tr.epochs}
+
+
+@pytest.mark.parametrize("name", ROUND_HOST_SPANS)
+def test_round_records_host_span(traced_round, name):
+    got = [e for e in traced_round["on"] if e[0] == name]
+    assert got, f"no {name} span in a traced round"
+    for _, _, _, t0, dur, args in got:
+        assert dur >= 0.0 and args["round"] == 0
+    if name == "client.sample":
+        assert sorted(e[5]["client"] for e in got) == \
+            list(range(traced_round["clients"]))
+    if name == "jit.compile":
+        assert all(e[5]["fun"] for e in got)
+    # the driver's own spans are parts of the round
+    if name in ("client.sample", "round.apply_push"):
+        assert sum(e[4] for e in got) <= traced_round["wall"]
+
+
+def test_step_spans_are_parts_of_their_epoch(traced_round):
+    events = traced_round["on"]
+    epochs = [e for e in events if e[0] == "client.train_epoch"]
+    assert len(epochs) == traced_round["clients"] * traced_round["epochs"]
+    for _, _, tid, a, dur, args in epochs:
+        parts = [e for e in events
+                 if e[0] in ("client.step_inputs", "client.step_dispatch")
+                 and e[2] == tid and a <= e[3] and e[3] + e[4] <= a + dur]
+        kinds = {e[0] for e in parts}
+        assert kinds == {"client.step_inputs", "client.step_dispatch"}, args
+        assert sum(e[4] for e in parts) <= dur
+        # the step spans carry no args of their own: only the context
+        assert all(e[5] == {"round": 0} for e in parts)
+
+
+def test_untraced_round_records_nothing(traced_round):
+    assert traced_round["off"] == []
+    assert trace.TRACE.span("client.step_inputs") is NOOP_SPAN
+    assert trace.TRACE.span("client.sample", args={"client": 0}) \
+        is NOOP_SPAN
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_compile_span_and_counter(enabled):
+    import jax
+    trace.install_jax_hooks()
+    trace.install_jax_hooks()               # idempotent: one listener
+
+    def fresh_kernel(x):
+        return x * 3.0 + 1.0
+
+    f = jax.jit(fresh_kernel)
+    x = np.ones(5, np.float32)
+    before = REGISTRY.snapshot("jit.")["jit.compiles"]
+    if enabled:
+        trace.TRACE.enable()
+    jax.block_until_ready(f(x))
+    got = [e for e in trace.TRACE.events if e[0] == "jit.compile"]
+    assert REGISTRY.snapshot("jit.")["jit.compiles"] == before + 1
+    if enabled:
+        assert len(got) == 1 and "fresh_kernel" in got[0][5]["fun"]
+        assert got[0][4] > 0.0
+    else:
+        assert got == []
+    jax.block_until_ready(f(x))             # cached: neither moves
+    assert REGISTRY.snapshot("jit.")["jit.compiles"] == before + 1
+    assert [e for e in trace.TRACE.events if e[0] == "jit.compile"] == got
 
 
 # -- chrome export + merge ----------------------------------------------------
