@@ -18,11 +18,18 @@ client's local embedding cache (h^l pulled from the embedding server).
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterator, Sequence
+from typing import Iterator
 
 import numpy as np
 
+from repro.obsv.metrics import REGISTRY
+
 from .partition import ClientShard
+
+#: local frontier vertices that drew in-neighbours, and those of them with
+#: more eligible in-edges than the fanout (drawn by Floyd's algorithm)
+_DRAWN = REGISTRY.counter("sampler.vertices_drawn")
+_SUBSAMPLED = REGISTRY.counter("sampler.vertices_subsampled")
 
 
 @dataclasses.dataclass
@@ -69,6 +76,28 @@ def _round_up(n: int, m: int = 128) -> int:
     return max(m, ((n + m - 1) // m) * m)
 
 
+def _local_first_csr(shard: ClientShard):
+    """A copy of the shard's in-edge CSR with each row's local sources
+    moved ahead of its remote ones (order within each kind kept), and
+    each row's count of local sources.  The shard's own arrays are left
+    as they are: propagation and evaluation read them in their order."""
+    indptr = np.asarray(shard.indptr, np.int64)
+    indices = np.asarray(shard.indices)
+    deg = np.diff(indptr)
+    is_local = indices < shard.num_local
+    n_before = np.zeros(len(indices) + 1, np.int64)   # local edges before e
+    np.cumsum(is_local, out=n_before[1:])
+    n_local = n_before[indptr[1:]] - n_before[indptr[:-1]]
+    row = np.repeat(np.arange(len(deg)), deg)
+    first = indptr[row]
+    local_rank = n_before[:-1] - n_before[first]
+    remote_rank = np.arange(len(indices)) - first - local_rank
+    dest = first + np.where(is_local, local_rank, n_local[row] + remote_rank)
+    nbrs = np.empty(len(indices), indices.dtype)
+    nbrs[dest] = indices
+    return indptr, nbrs, n_local
+
+
 class NeighborSampler:
     """Uniform fanout sampler over a :class:`ClientShard`."""
 
@@ -97,31 +126,46 @@ class NeighborSampler:
             for h in range(num_layers)
         ]
         self._train = shard.train_vertices()
+        self._indptr, self._nbrs, self._n_local_nbrs = _local_first_csr(shard)
+        self._deg = np.diff(self._indptr)
+        self._pos = np.zeros(n_total, np.int64)   # node id -> block position
 
     # -- sampling --------------------------------------------------------
+
+    def _floyd(self, d: np.ndarray) -> np.ndarray:
+        """A uniform ``fanout``-subset of ``[0, d)`` for each row of ``d``
+        (every ``d > fanout``), without replacement: Floyd's algorithm,
+        one draw per column over all rows at once."""
+        f = self.fanout
+        out = np.empty((len(d), f), np.int64)
+        for i in range(f):
+            j = d - f + i
+            t = self.rng.integers(0, j + 1)
+            taken = (out[:, :i] == t[:, None]).any(axis=1)
+            out[:, i] = np.where(taken, j, t)
+        return out
 
     def _sample_neighbors(self, frontier: np.ndarray, local_only: bool):
         """Sample ≤fanout in-neighbours for each LOCAL node in frontier.
 
-        Returns (edge_src_ids, edge_dst_ids) in shard-local node ids.
-        Remote frontier nodes are skipped (rule 2)."""
-        sh = self.shard
-        srcs, dsts = [], []
-        for u in frontier:
-            if u >= sh.num_local:      # remote: path terminates
-                continue
-            nbrs = sh.indices[sh.indptr[u]: sh.indptr[u + 1]]
-            if local_only:
-                nbrs = nbrs[nbrs < sh.num_local]
-            if len(nbrs) == 0:
-                continue
-            if len(nbrs) > self.fanout:
-                nbrs = self.rng.choice(nbrs, size=self.fanout, replace=False)
-            srcs.append(nbrs.astype(np.int64))
-            dsts.append(np.full(len(nbrs), u, dtype=np.int64))
-        if not srcs:
-            return np.zeros(0, np.int64), np.zeros(0, np.int64)
-        return np.concatenate(srcs), np.concatenate(dsts)
+        Returns (edge_src_ids, edge_dst_ids) in shard-local node ids,
+        grouped by dst in frontier order.  Remote frontier nodes are
+        skipped (rule 2); with ``local_only`` only the row's local
+        prefix is eligible."""
+        f = self.fanout
+        u = frontier[frontier < self.shard.num_local]
+        d = (self._n_local_nbrs if local_only else self._deg)[u]
+        u, d = u[d > 0], d[d > 0]
+        cols = np.tile(np.arange(f, dtype=np.int64), (len(u), 1))
+        sub = d > f
+        n_sub = int(np.count_nonzero(sub))
+        if n_sub:
+            cols[sub] = self._floyd(d[sub])
+        _DRAWN.inc(len(u))
+        _SUBSAMPLED.inc(n_sub)
+        take = cols < d[:, None]
+        e_src = self._nbrs[(self._indptr[u][:, None] + cols)[take]]
+        return e_src.astype(np.int64), np.repeat(u, np.minimum(d, f))
 
     def sample_batch(self, seeds: np.ndarray) -> MiniBatch:
         sh, L = self.shard, self.L
@@ -130,23 +174,24 @@ class NeighborSampler:
         for hop in range(1, L + 1):
             cur = layers[-1]
             e_src, e_dst = self._sample_neighbors(cur, local_only=(hop == L))
-            new = np.setdiff1d(np.unique(e_src), cur)
-            layers.append(np.concatenate([cur, new]))   # dst-prefix ordering
+            new = np.zeros(len(self._pos), bool)  # sorted e_src not in cur
+            new[e_src] = True
+            new[cur] = False
+            # dst-prefix ordering
+            layers.append(np.concatenate([cur, np.flatnonzero(new)]))
             layer_edges.append((e_src, e_dst))
 
         blocks: list[Block] = []
         remote_used: list[np.ndarray] = []
+        pos = self._pos
         # GNN layer l (1-indexed) consumes node set layers[L-l+1], produces
         # layers[L-l]; edges are layer_edges[L-l].
         for l in range(1, L + 1):
             src_nodes = layers[L - l + 1]
             dst_nodes = layers[L - l]
             e_src, e_dst = layer_edges[L - l]
-            pos = {int(u): i for i, u in enumerate(src_nodes)}
-            es = np.fromiter((pos[int(u)] for u in e_src), dtype=np.int64,
-                             count=len(e_src))
-            ed = np.fromiter((pos[int(u)] for u in e_dst), dtype=np.int64,
-                             count=len(e_dst))
+            pos[src_nodes] = np.arange(len(src_nodes))
+            es, ed = pos[e_src], pos[e_dst]
             p_src = self._p_nodes[L - l + 1]
             p_dst = self._p_nodes[L - l]
             p_e = self._p_edges[L - l]

@@ -19,20 +19,24 @@ def test_full_federated_session_matches_paper_shape():
     rounds, FedAvg, validation — accuracy rises, phases are populated,
     OptimES reduces communication vs EmbC."""
     g = make_graph("reddit", scale=0.15, seed=5)
-    runs = {}
-    for name in ("E", "OPG"):
-        tr = FederatedGNNTrainer(g, 3, default_strategies()[name],
-                                 batch_size=64, seed=0)
-        stats = tr.train(6)
-        runs[name] = (tr, stats)
-        accs = [s.accuracy for s in stats]
-        assert max(accs[2:]) > accs[0]          # learning happens
-    (tr_e, e), (tr_o, o) = runs["E"], runs["OPG"]
-    # OPG holds fewer embeddings at the server and ships fewer bytes
-    assert o[-1].embeddings_stored < e[-1].embeddings_stored
-    assert tr_o.server.log.bytes < tr_e.server.log.bytes
-    # peak accuracy stays comparable (within a few points)
-    assert peak_accuracy(o) > peak_accuracy(e) - 0.05
+    peaks = {"E": [], "OPG": []}
+    for seed in (0, 1, 2):
+        runs = {}
+        for name in ("E", "OPG"):
+            tr = FederatedGNNTrainer(g, 3, default_strategies()[name],
+                                     batch_size=64, seed=seed)
+            stats = tr.train(6)
+            runs[name] = (tr, stats)
+            peaks[name].append(peak_accuracy(stats))
+            accs = [s.accuracy for s in stats]
+            assert max(accs[2:]) > accs[0]          # learning happens
+        (tr_e, e), (tr_o, o) = runs["E"], runs["OPG"]
+        # OPG holds fewer embeddings at the server and ships fewer bytes
+        assert o[-1].embeddings_stored < e[-1].embeddings_stored
+        assert tr_o.server.log.bytes < tr_e.server.log.bytes
+    # peak accuracy stays comparable (within a few points), over trainer
+    # seeds: one seed's peak on ~200 test vertices swings by several points
+    assert np.mean(peaks["OPG"]) > np.mean(peaks["E"]) - 0.05, peaks
 
 
 def test_transformer_training_loop_learns():

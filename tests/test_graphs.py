@@ -199,3 +199,191 @@ def test_sampler_fanout_bound_property(fanout, L, seed):
         # remote dst rows carry valid cache slots
         slots = blk.dst_remote_slot[blk.dst_remote_mask]
         assert np.all(slots < max(1, sh.num_remote))
+
+
+# -- vectorised draw: uniformity, whole rows, hop L, shard untouched ----------
+
+def _drawn(mb, block=-1):
+    """(src, dst) shard-local ids of one block's sampled edges."""
+    b = mb.blocks[block]
+    m = b.edge_mask
+    return b.src_ids[b.edge_src[m]], b.src_ids[b.edge_dst[m]]
+
+
+def _eligible(sh, u, local_only):
+    nbrs = sh.indices[sh.indptr[u]: sh.indptr[u + 1]]
+    return nbrs[nbrs < sh.num_local] if local_only else nbrs
+
+
+def _interleaved_shard():
+    """Five local vertices (ids 5-8 remote): vertex 0 reads
+    [5, 1, 6, 2, 7, 3, 8], remote and local ids interleaved; vertices 1
+    and 2 read [4] and [0]; 3 and 4 read nothing."""
+    from repro.graphs.partition import ClientShard
+    indices = np.array([5, 1, 6, 2, 7, 3, 8, 4, 0], np.int32)
+    return ClientShard(
+        client_id=0, indptr=np.array([0, 7, 8, 9, 9, 9]), indices=indices,
+        global_ids=np.arange(9), num_local=5,
+        features=np.zeros((5, 2), np.float32), labels=np.zeros(5, np.int64),
+        train_mask=np.ones(5, bool), pull_nodes=np.arange(5, 9),
+        push_nodes=np.zeros(0, np.int64), all_pull_nodes=np.arange(5, 9))
+
+
+@pytest.mark.parametrize("fanout", [2, 5])
+@pytest.mark.parametrize("local_only", [False, True])
+def test_subsampled_edges_drawn_uniformly(small_shards, fanout, local_only):
+    """A vertex with more eligible in-edges than the fanout draws each
+    with frequency fanout/d: chi-square over fixed-seed batches."""
+    sh = small_shards[0][0]
+    # L=1 makes hop 1 the hop-L (local-only) draw; L=2 draws all in-edges
+    s = NeighborSampler(sh, fanout, 1 if local_only else 2, batch_size=1,
+                        seed=3)
+    d_all = [len(_eligible(sh, u, local_only)) for u in range(sh.num_local)]
+    u = int(np.argmax(d_all))
+    elig = _eligible(sh, u, local_only)
+    d = len(elig)
+    assert d > fanout
+    n = 3000
+    counts = dict.fromkeys(elig.tolist(), 0)
+    for _ in range(n):
+        src, dst = _drawn(s.sample_batch(np.array([u])))
+        assert np.all(dst == u) and len(src) == fanout
+        assert len(np.unique(src)) == fanout
+        for v in src.tolist():
+            counts[v] += 1        # KeyError: an ineligible source
+    obs = np.array(list(counts.values()), float)
+    exp = n * fanout / d
+    chi2 = float(((obs - exp) ** 2 / exp).sum())
+    # E[chi2] = d - fanout without replacement; allow a wide margin
+    assert chi2 < (d - 1) + 6 * np.sqrt(2 * (d - 1)), (chi2, d)
+    assert obs.min() > 0
+
+
+@pytest.mark.parametrize("local_only", [False, True])
+def test_short_rows_taken_whole(small_shards, local_only):
+    """With d ≤ fanout every eligible in-edge is taken, each once."""
+    fanout = 5
+    for sh in small_shards[0]:
+        d = np.array([len(_eligible(sh, u, local_only))
+                      for u in range(sh.num_local)])
+        short = np.nonzero((d > 0) & (d <= fanout))[0]
+        assert len(short)
+        s = NeighborSampler(sh, fanout, 1 if local_only else 2,
+                            batch_size=len(short), seed=5)
+        src, dst = _drawn(s.sample_batch(short))
+        for u in short:
+            got = np.sort(src[dst == u])
+            assert np.array_equal(got, np.sort(_eligible(sh, u, local_only)))
+
+
+@pytest.mark.parametrize("fanout", [1, 2, 3, 5])
+@pytest.mark.parametrize("which", ["built", "interleaved"])
+def test_hop_l_draws_local_sources_only(small_shards, fanout, which):
+    """Hop L (here the only hop) draws min(fanout, local in-degree) local
+    sources from rows that interleave local and remote ids."""
+    sh = small_shards[0][0] if which == "built" else _interleaved_shard()
+    rows = [sh.indices[sh.indptr[u]: sh.indptr[u + 1]] >= sh.num_local
+            for u in range(sh.num_local)]
+    # precondition: some row reads a remote id before a local one
+    assert any(np.any(r[:-1] & ~r[1:]) for r in rows)
+    s = NeighborSampler(sh, fanout, 1, batch_size=sh.num_local, seed=11)
+    for _ in range(5):
+        src, dst = _drawn(s.sample_batch(np.arange(sh.num_local)))
+        assert np.all(src < sh.num_local)
+        n_local = np.array([np.count_nonzero(~r) for r in rows])
+        per_dst = np.bincount(dst, minlength=sh.num_local)
+        assert np.array_equal(per_dst, np.minimum(fanout, n_local))
+        for u in range(sh.num_local):
+            got = src[dst == u]
+            assert len(np.unique(got)) == len(got)
+            assert np.all(np.isin(got, _eligible(sh, u, True)))
+
+
+@pytest.mark.parametrize("ci", [0, 1, 2, 3, "interleaved"])
+def test_local_first_rows_match_loop(small_shards, ci):
+    """The sampler's row copy is each shard row with its local sources
+    moved ahead of the remote ones, order within each kind kept."""
+    from repro.graphs.sampler import _local_first_csr
+    sh = _interleaved_shard() if ci == "interleaved" else small_shards[0][ci]
+    indptr, nbrs, n_local = _local_first_csr(sh)
+    assert np.array_equal(indptr, sh.indptr)
+    for u in range(sh.num_local):
+        r = sh.indices[sh.indptr[u]: sh.indptr[u + 1]]
+        want = np.concatenate([r[r < sh.num_local], r[r >= sh.num_local]])
+        assert np.array_equal(nbrs[indptr[u]: indptr[u + 1]], want)
+        assert n_local[u] == np.count_nonzero(r < sh.num_local)
+
+
+@pytest.mark.parametrize("ci", [0, 1, 2, 3])
+def test_sampling_leaves_shard_arrays_untouched(small_shards, ci):
+    sh = small_shards[0][ci]
+    before = (sh.indptr.tobytes(), sh.indices.tobytes(), sh.indices.dtype)
+    s = NeighborSampler(sh, 3, 3, batch_size=16, seed=2)
+    for mb in s.epoch():
+        pass
+    s.sample_batch(sh.train_vertices()[:8])
+    assert (sh.indptr.tobytes(), sh.indices.tobytes(),
+            sh.indices.dtype) == before
+
+
+def _benchmark_rule_check():
+    """The benchmark's check of the sampler's choices, loaded by path
+    (it imports nothing of the program)."""
+    import importlib.util
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1]
+            / "perfbench" / "yardstick" / "reference.py")
+    spec = importlib.util.spec_from_file_location("_bench_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("fanout,L", [(2, 2), (3, 2), (5, 3)])
+@pytest.mark.parametrize("ci", [0, 3])
+def test_sampler_passes_benchmark_rule_check(small_graph, small_shards,
+                                             fanout, L, ci):
+    """Graph edges, each once, min(fanout, eligible) per local dst, layer
+    1 reads local features only, the last layer's outputs are the seeds."""
+    ref = _benchmark_rule_check()
+    shards, part = small_shards
+    g = small_graph
+    gi = ref.GraphIndex(np.asarray(g.indptr), np.asarray(g.indices),
+                        np.asarray(part), len(shards))
+    sh = shards[ci]
+    s = NeighborSampler(sh, fanout, L, batch_size=16, seed=9)
+    for mb in s.epoch():
+        batch = {"blocks": [{k: getattr(b, k) for k in
+                             ("edge_src", "edge_dst", "edge_mask",
+                              "dst_mask")} for b in mb.blocks],
+                 "input_ids": mb.input_ids, "seeds": mb.seeds,
+                 "seed_mask": mb.seed_mask}
+        ref.block_to_edges(batch, np.asarray(sh.global_ids), client=ci,
+                           gi=gi, fanout=fanout,
+                           retention=int(gi.deg.max()),
+                           train_mask=np.asarray(g.train_mask))
+
+
+def test_sampler_counters_count_draws(small_shards):
+    from repro.obsv.metrics import REGISTRY
+    names = ("sampler.vertices_drawn", "sampler.vertices_subsampled")
+    sh = small_shards[0][0]
+    fanout = 2
+    frontier = np.arange(len(sh.global_ids))     # locals, then remotes
+    s = NeighborSampler(sh, fanout, 1, batch_size=len(frontier), seed=4)
+    n_local = np.array([len(_eligible(sh, u, True))
+                        for u in range(sh.num_local)])
+    before = REGISTRY.snapshot("sampler.")
+    s.sample_batch(frontier)     # one hop, local-only; remotes skipped
+    after = REGISTRY.snapshot("sampler.")
+    drawn, sub = (after[n] - before[n] for n in names)
+    assert drawn == np.count_nonzero(n_local > 0)
+    assert sub == np.count_nonzero(n_local > fanout)
+    # a whole epoch over three hops
+    s = NeighborSampler(sh, fanout, 3, batch_size=16, seed=4)
+    before = REGISTRY.snapshot("sampler.")
+    for mb in s.epoch():
+        pass
+    after = REGISTRY.snapshot("sampler.")
+    drawn, sub = (after[n] - before[n] for n in names)
+    assert 0 < sub <= drawn

@@ -535,6 +535,19 @@ def _scrapeable(endpoints) -> list | None:
     return scrapes
 
 
+def _wait_listening(endpoints, timeout: float = 120.0) -> None:
+    """Block until every endpoint answers a scrape: a client started
+    before its server listens is refused and exits."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        try:
+            teleserve.scrape_all(endpoints)
+            return
+        except (ConnectionError, OSError, json.JSONDecodeError):
+            time.sleep(0.2)
+    pytest.fail(f"servers never listened: {endpoints}")
+
+
 @pytest.mark.slow
 def test_six_process_obs_dump_acceptance(tmp_path):
     """Acceptance: coordinator + 2 workers + 2 embed shards + serving
@@ -572,7 +585,7 @@ def test_six_process_obs_dump_acceptance(tmp_path):
              "--graph-seed", "3", "--clients", "2", "--strategy", "E",
              "--rounds", "1", "--cache-rows", "5000"],
             env=env, stdout=subprocess.DEVNULL))
-        time.sleep(1.0)
+        _wait_listening(endpoints[:3])    # coordinator and embed shards
         for i, wp in enumerate((w0, w1)):
             procs.append(subprocess.Popen(
                 [sys.executable, "-m", "repro.launch.fed_worker",
