@@ -79,7 +79,7 @@ def main(argv=None) -> int:
         print(f"calibrate: {e}", file=sys.stderr)
         return 3
     sys.path.insert(0, str(root / "src"))
-    from perfbench.drivers import federated_rounds as fr
+    fr = harness.driver_of(files["traffic"])
     from perfbench.yardstick import compare
     cfg = files["config"]
     rows = []

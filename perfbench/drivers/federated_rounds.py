@@ -11,6 +11,12 @@ then drives the same trainer's ``run_round`` until the next round would
 not fit, and counts the programs compiled in it (``window_compiles`` on
 stderr).  After the window the plain reference (``yardstick/
 reference.py``) recomputes the whole set-up round from the seed.
+
+Nothing here depends on the architecture: the configuration's ``conv``
+names its model module (``yardstick/models/<conv>.py``), which gives the
+widths, the weights, the program's parameter layout, the forward pass,
+the loss and the FLOPs.  Each layer's weights travel as one tuple of
+leaves.
 """
 
 from __future__ import annotations
@@ -22,6 +28,7 @@ import time
 
 import numpy as np
 
+from perfbench import harness
 from perfbench.yardstick import compare, flops, graphgen, reference
 
 #: program spans inside ``run_round`` (``obsv/trace.py`` names)
@@ -92,10 +99,13 @@ class RoundRecorder:
                 del s.epoch
 
 
-def _widths(cfg: dict) -> tuple[int, ...]:
-    L = int(cfg["layers"])
-    return (int(cfg["features"]),) + (int(cfg["hidden"]),) * (L - 1) \
-        + (int(cfg["classes"]),)
+def _model(cfg: dict):
+    return harness.model_of(cfg["conv"])
+
+
+def _host(leaves) -> list[tuple]:
+    """Per-layer tuples of leaves as host arrays."""
+    return [tuple(np.asarray(a) for a in layer) for layer in leaves]
 
 
 def precision(cfg: dict):
@@ -121,6 +131,24 @@ def build(cfg: dict, seed: int):
 
 
 def _build(cfg: dict, seed: int):
+    model = _model(cfg)
+    tr, graph = trainer(cfg, seed)
+    params0 = model.init(reference.key_from_seed(seed, 2), cfg)
+    tr.params = model.to_program(params0)
+    tr.pretrain_round()
+    rec = RoundRecorder(tr, int(cfg["checked_steps"]))
+    pulled_before = _pulled(tr)
+    stats = rec.run_round(0)
+    seen = {"epochs": rec.epochs, "first": rec.first,
+            "pulled_before": pulled_before, "pulled_after": _pulled(tr),
+            "avg": _host(model.from_program(tr.params)),
+            "acc": float(stats.accuracy), "struct": structure_of(tr)}
+    return tr, seen, graph, _host(params0)
+
+
+def trainer(cfg: dict, seed: int):
+    """The deployment's graph, its features drawn from the seed, and the
+    program's trainer over it.  Returns ``(trainer, graph)``."""
     from repro.core import FederatedGNNTrainer, default_strategies
     from repro.graphs.graph import Graph
 
@@ -139,43 +167,28 @@ def _build(cfg: dict, seed: int):
         epochs_per_round=int(cfg["epochs"]), lr=float(cfg["lr"]),
         seed=int(cfg["trainer_seed"]),
         eval_max_edges=int(cfg["eval_max_edges"]))
-    params0 = reference.init_params(reference.key_from_seed(seed, 2),
-                                    _widths(cfg))
-    tr.params = [{"w_neigh": w, "b": b} for w, b in params0]
-    tr.pretrain_round()
-    rec = RoundRecorder(tr, int(cfg["checked_steps"]))
-    pulled_before = _pulled(tr)
-    stats = rec.run_round(0)
-    seen = {"epochs": rec.epochs, "first": rec.first,
-            "pulled_before": pulled_before, "pulled_after": _pulled(tr),
-            "avg": [(np.asarray(p["w_neigh"]), np.asarray(p["b"]))
-                    for p in tr.params],
-            "acc": float(stats.accuracy), "struct": structure_of(tr)}
     graph = {"indptr": st["indptr"], "indices": st["indices"],
              "labels": st["labels"], "train_mask": st["train_mask"],
              "features": feats}
-    return tr, seen, graph, [(np.asarray(w), np.asarray(b))
-                             for w, b in params0]
+    return tr, graph
 
 
 def counts(tr, cfg: dict) -> dict:
     """Work per round, from the trainer's shards: steps, model FLOPs
-    and int8 wire bytes."""
-    steps = flops_total = codec_rows = 0
+    and int8 wire bytes (each exchanged layer's rows at its width)."""
+    model = _model(cfg)
+    steps = flops_total = rows = 0
     for ci in range(tr.k):
         sh = tr.shards[ci]
         n_batches = -(-len(sh.train_vertices()) // int(cfg["batch"]))
         steps += int(cfg["epochs"]) * n_batches
         flops_total += int(cfg["epochs"]) * n_batches * \
-            flops.train_step_flops(batch=int(cfg["batch"]),
-                                   fanout=int(cfg["fanout"]),
-                                   widths=list(_widths(cfg)),
-                                   shard_vertices=len(sh.global_ids))
-        codec_rows += (len(sh.pull_nodes) + len(sh.push_nodes)) \
-            * (int(cfg["layers"]) - 1)
+            model.step_flops(cfg, len(sh.global_ids))
+        rows += len(sh.pull_nodes) + len(sh.push_nodes)
     return {"steps_per_round": steps, "flops_per_round": flops_total,
-            "codec_bytes_per_round":
-                flops.int8_codec_bytes(codec_rows, int(cfg["hidden"]))}
+            "codec_bytes_per_round": sum(
+                flops.int8_codec_bytes(rows, w)
+                for w in model.exchanged_widths(cfg))}
 
 
 def structure_of(tr) -> dict:
@@ -199,13 +212,13 @@ def program_side(seen: dict, cfg: dict) -> dict:
     after the last checked step and the rows it pulled for the round;
     then the rows pulled after the round, the averaged model and the
     round's accuracy."""
+    model = _model(cfg)
     b1 = float(cfg["adam"]["b1"])
     first = {}
     for ci, recs in seen["first"].items():
-        grad = [(np.asarray(m["w_neigh"]) / (1 - b1),
-                 np.asarray(m["b"]) / (1 - b1)) for m in recs[0]["mu"]]
-        params = [(np.asarray(p["w_neigh"]), np.asarray(p["b"]))
-                  for p in recs[-1]["params"]]
+        grad = [tuple(np.asarray(m) / (1 - b1) for m in layer)
+                for layer in model.from_program(recs[0]["mu"])]
+        params = _host(model.from_program(recs[-1]["params"]))
         first[ci] = {"loss": [r["loss"] for r in recs], "grad": grad,
                      "params": params, "cache": seen["pulled_before"][ci]}
     return {"first": first, "round": {"pulled": seen["pulled_after"],
@@ -230,6 +243,7 @@ def reference_side(seen: dict, cfg: dict, graph: dict, params0, *,
     ``eval`` (called with the averaged and the starting model).  Raises
     ``ValueError`` where a choice breaks the rules."""
     import jax.numpy as jnp
+    model = _model(cfg)
     plant = plant or {}
     struct = seen["struct"]
     part = struct["part"]
@@ -238,7 +252,8 @@ def reference_side(seen: dict, cfg: dict, graph: dict, params0, *,
     feats = jnp.asarray(graph["features"])
     rt = reference.int8_roundtrip
     h_pre = [np.asarray(h) for h in
-             reference.pretrain_h(params0, graph["features"], gi, mode=mode)]
+             reference.pretrain_h(model.propagate, params0,
+                                  graph["features"], gi, mode=mode)]
     seen_rows, have = _pulled_table(seen["pulled_before"], struct, h_pre)
     srv_pre = [reference.take_ties(h, rt(h), s, have)
                for h, s in zip(h_pre, seen_rows)]
@@ -279,41 +294,46 @@ def reference_side(seen: dict, cfg: dict, graph: dict, params0, *,
         stacked = plant.get("stacked", _same)(
             reference.stack_batches(packed))
         losses, grads, ps = reference.local_round(
-            [(jnp.asarray(w), jnp.asarray(b)) for w, b in params0], stacked,
-            feats, tables, mode=mode, lr=float(cfg["lr"]),
-            b1=float(adam["b1"]), b2=float(adam["b2"]), eps=float(adam["eps"]))
+            [tuple(jnp.asarray(a) for a in layer) for layer in params0],
+            stacked, feats, tables, loss=model.loss, mode=mode,
+            lr=float(cfg["lr"]), b1=float(adam["b1"]), b2=float(adam["b2"]),
+            eps=float(adam["eps"]))
         n = int(cfg["checked_steps"])
         first[ci] = {
             "loss": [float(x) for x in np.asarray(losses[:n])],
-            "grad": [(np.asarray(gw[0]), np.asarray(gb[0]))
-                     for gw, gb in grads],
-            "params": [(np.asarray(w[n - 1]), np.asarray(b[n - 1]))
-                       for w, b in ps],
+            "grad": _host(_at(grads, 0)),
+            "params": _host(_at(ps, n - 1)),
             "cache": [np.asarray(t)[struct["pull_nodes"][ci]]
                       for t in tables]}
         at = int(cfg["push_after_epoch"]) * per_epoch - 1
-        h = reference.client_h([(w[at], b[at]) for w, b in ps], feats, gi,
+        h = reference.client_h(model.propagate, _at(ps, at), feats, gi,
                                e_src, e_dst, tables, client=ci, mode=mode)
         mine = (part == ci)[:, None]
         after = [np.where(mine, np.asarray(x), a) for x, a in zip(h, after)]
-        finals.append([(w[-1], b[-1]) for w, b in ps])
+        finals.append(_at(ps, -1))
         weights.append(float(len(train)))
     server = plant.get("server", lambda a, b: a)(
         [rt(a + r) for a, r in zip(after, residual)], srv_pre)
     pulled = [rt(s) for s in server]
     avg = plant.get("average", _average)(finals, weights)
-    avg = [(np.asarray(w), np.asarray(b)) for w, b in avg]
+    avg = _host(avg)
     sel = reference.eval_vertices(graph["indptr"], int(cfg["eval_max_edges"]),
                                   int(cfg["trainer_seed"]))
     acc = reference.accuracy(
-        plant.get("eval", lambda a, p0: a)(avg, params0), graph["features"],
-        graph["labels"], graph["train_mask"], gi, sel, mode=mode)
+        model.propagate, plant.get("eval", lambda a, p0: a)(avg, params0),
+        graph["features"], graph["labels"], graph["train_mask"], gi, sel,
+        mode=mode)
     return {"first": first,
             "round": {"pulled": {ci: [t[struct["pull_nodes"][ci]]
                                       for t in pulled] for ci in range(k)},
                       "before": {ci: [t[struct["pull_nodes"][ci]]
                                       for t in before] for ci in range(k)},
                       "avg": avg, "acc": acc}}
+
+
+def _at(stepped, i):
+    """Each leaf of per-step stacked leaves at step ``i``."""
+    return [tuple(a[i] for a in layer) for layer in stepped]
 
 
 def _pulled_table(pulled: dict, struct: dict, like) -> tuple[list, np.ndarray]:
@@ -333,7 +353,8 @@ def _average(models, weights):
     """FedAvg: the clients' models weighted by their training vertices."""
     total = sum(weights)
     return [tuple(sum(w * m[l][i] for w, m in zip(weights, models)) / total
-                  for i in range(2)) for l in range(len(models[0]))]
+                  for i in range(len(models[0][l])))
+            for l in range(len(models[0]))]
 
 
 def gaps_of(side: dict, ref: dict, params0) -> dict:

@@ -1,10 +1,12 @@
 """Finds a cell's files by name, runs its driver on the chip, and prints
 the result line.
 
-Nothing here names a configuration, a traffic mix or a metric: a cell
-of ``BENCHMARK.json`` names its configuration (``configs/<config>.json``)
-and its traffic (``traffic/<traffic>.json``, whose ``driver`` names a
-module under ``drivers/``); each per-layer metric is read by
+Nothing here names a configuration, a traffic mix, a model or a metric:
+a cell of ``BENCHMARK.json`` names its configuration
+(``configs/<config>.json``, whose ``conv`` names the model module
+``yardstick/models/<conv>.py``) and its traffic
+(``traffic/<traffic>.json``, whose ``driver`` names a module under
+``drivers/``); each per-layer metric is read by
 ``metrics/<metric name>.py``; each cell's limits for ``correct`` are in
 ``limits/<cell>.json``.
 """
@@ -12,6 +14,7 @@ module under ``drivers/``); each per-layer metric is read by
 from __future__ import annotations
 
 import contextlib
+import functools
 import importlib
 import importlib.util
 import json
@@ -25,10 +28,16 @@ HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
 #: JAX's persistent compilation cache: a fixed path inside the checkout
 CACHE_DIR = ROOT / ".jax_cache"
+#: where a configuration's ``conv`` finds its model module
+MODELS = HERE / "yardstick" / "models"
 
 
 class NoChip(RuntimeError):
     """JAX found no accelerator, or fewer chips than the cell needs."""
+
+
+class NoModel(LookupError):
+    """A configuration's ``conv`` names no model module."""
 
 
 def load_json(path: pathlib.Path) -> dict:
@@ -63,6 +72,28 @@ def load_module(path: pathlib.Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def model_of(conv: str):
+    """The model module a configuration's ``conv`` names; raises
+    :class:`NoModel`, listing the known models, where there is none."""
+    path = MODELS / f"{conv}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in MODELS.glob("*.py"))
+        raise NoModel(f"no model {conv!r} under {MODELS}; known: {known}")
+    return _load_once(path)
+
+
+@functools.lru_cache(maxsize=None)
+def _load_once(path: pathlib.Path):
+    """One module object a path, so that its jitted functions keep their
+    compiled programs from call to call."""
+    return load_module(path)
+
+
+def driver_of(traffic: dict):
+    """The driver module a traffic mix's ``driver`` names."""
+    return importlib.import_module(f"perfbench.drivers.{traffic['driver']}")
 
 
 def read_layer_metrics(metrics: list[dict], ctx: dict,
@@ -176,8 +207,7 @@ def run_cell(args, t_start: float) -> tuple[dict, list]:
     from perfbench.yardstick.peaks import peaks_for
     peaks = peaks_for(dev["kind"])
     sys.path.insert(0, str(ROOT / "src"))
-    driver = importlib.import_module(
-        f"perfbench.drivers.{files['traffic']['driver']}")
+    driver = driver_of(files["traffic"])
     tracer = Tracer(bool(args.trace))
     res = driver.run(files["config"], files["traffic"], seed=args.seed,
                      seconds=float(args.seconds), t_start=t_start,

@@ -18,6 +18,8 @@ sys.path.insert(0, str(ROOT))
 from perfbench import harness  # noqa: E402
 from perfbench.yardstick import flops, graphgen, peaks, trace  # noqa: E402
 
+graphconv = harness.model_of("graphconv")
+
 TINY = {"graph_seed": 3, "vertices": 600, "avg_degree": 40.0, "classes": 6,
         "features": 20, "train_frac": 0.5, "homophily": 0.9,
         "intra_pair_cap": 0.9, "feature_noise": 1.0}
@@ -64,8 +66,8 @@ def test_generator_cap_moves_degree_across_classes():
 def test_hop_sizes_and_step_flops():
     assert flops.hop_sizes(64, 5, 3, 10_000) == [64, 384, 2304, 10_000]
     assert flops.hop_sizes(64, 5, 3, 100) == [64, 100, 100, 100]
-    got = flops.train_step_flops(batch=2, fanout=1, widths=[3, 4, 5],
-                                 shard_vertices=100)
+    got = graphconv.train_step_flops(batch=2, fanout=1, widths=[3, 4, 5],
+                                     shard_vertices=100)
     # layer 1: 4 dst, 3 -> 4; layer 2: 2 dst, 4 -> 5
     l1 = 2 * (2 * 4 * 3 * 4) + 2 * (4 * 3 * 3)
     l2 = 3 * (2 * 2 * 4 * 5) + 2 * (2 * 3 * 4)

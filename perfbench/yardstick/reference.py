@@ -1,17 +1,16 @@
 """Plain reference for one whole federated round of every client.
 
-What it computes follows the published description: GraphConv
-(Kipf & Welling 2017; mean over N(v) and v itself, then a dense layer,
-ReLU between layers), softmax cross-entropy over the seed vertices,
-Adam (Kingma & Ba 2015), the federated rules of OptimES
-(arXiv:2509.22922): a remote vertex's h^l comes from its owner's pushed
-embedding (§3.2), pre-training uses only local edges (§3.2.1), the
-push of a round is computed from the model after epoch ε−1 (§4.2), and
-the server averages the clients' models weighted by their training
-vertices (FedAvg).  The wire is per-row symmetric int8 (scale = row
-absmax / 127, round half to even) with error feedback: each push carries
-the previous push's rounding error of that row (EF-SGD).  It imports
-nothing of the system under test.
+What it computes follows the published description: the configuration's
+model (``models/<conv>.py``: its forward pass and a step's softmax
+cross-entropy over the seed vertices), Adam (Kingma & Ba 2015), the
+federated rules of OptimES (arXiv:2509.22922): a remote vertex's h^l
+comes from its owner's pushed embedding (§3.2), pre-training uses only
+local edges (§3.2.1), the push of a round is computed from the model
+after epoch ε−1 (§4.2), and the server averages the clients' models
+weighted by their training vertices (FedAvg).  The wire is per-row
+symmetric int8 (scale = row absmax / 127, round half to even) with
+error feedback: each push carries the previous push's rounding error of
+that row (EF-SGD).  It imports nothing of the system under test.
 
 Its inputs are the deployment's graph and features, the weights the
 benchmark made from the seed, the partition, which in-edges each
@@ -24,7 +23,8 @@ Everything is float32; the int8 wire runs on the host.  ``mode="highest"`` runs 
 at ``HIGHEST`` precision (the reference); ``mode="high"`` runs each as
 three bfloat16 products with float32 sums, a_hi b_hi + a_hi b_lo +
 a_lo b_hi, which is what ``Precision.HIGH`` does on a TPU, written out
-so that it does the same on any backend (the control).
+so that it does the same on any backend (the control).  A model's
+products go through :func:`mm`.
 """
 
 from __future__ import annotations
@@ -41,7 +41,7 @@ def _split(x):
     return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
 
 
-def _mm(a, b, mode: str):
+def mm(a, b, mode: str):
     """float32 (a @ b) at the reference's or the control's precision."""
     if mode == "highest":
         return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
@@ -54,20 +54,7 @@ def _mm(a, b, mode: str):
     return dot(a_hi, b_hi) + (dot(a_hi, b_lo) + dot(a_lo, b_hi))
 
 
-# -- weights -------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("dims",))
-def init_params(key, dims: tuple[int, ...]):
-    """He-normal dense weights and zero biases, one (W, b) per layer, made
-    on the device in one call."""
-    out = []
-    for d_in, d_out in zip(dims[:-1], dims[1:]):
-        key, sub = jax.random.split(key)
-        w = jax.random.normal(sub, (d_in, d_out), jnp.float32) \
-            * jnp.sqrt(2.0 / d_in)
-        out.append((w, jnp.zeros((d_out,), jnp.float32)))
-    return out
-
+# -- seeds ---------------------------------------------------------------------
 
 def key_from_seed(seed: int, salt: int) -> jax.Array:
     """A PRNG key from a seed of any size (jax keeps only 32 bits)."""
@@ -261,55 +248,27 @@ def _pad_edges(e_src, e_dst, *masks):
     return [jnp.asarray(a) for a in out]
 
 
-@functools.partial(jax.jit, static_argnames=("mode",))
-def _propagate(params, x, e_src, e_dst, w_first, w_rest, own, tables, *,
-               mode):
-    """h^1..h^L of every vertex.  An edge's weight is ``w_first`` at
-    layer 1 and ``w_rest`` above; above layer 1 a source the client does
-    not own (``own`` false) reads its row from ``tables[l - 2]``.  Each
-    layer multiplies before it aggregates, ((sum h_u + h_v) W) = (sum
-    h_u W + h_v W): the same sum, so that the edge gather is hidden-wide
-    and not feature-wide."""
-    n = x.shape[0]
-    L = len(params)
-    h, outs = x, []
-    for l, (w, b) in enumerate(params, start=1):
-        z = _mm(h, w, mode)
-        wt = w_first if l == 1 else w_rest
-        src = z
-        if l > 1 and tables is not None:
-            src = jnp.where(own[:, None], z, _mm(tables[l - 2], w, mode))
-        agg = jax.ops.segment_sum(src[e_src] * wt[:, None], e_dst,
-                                  num_segments=n)
-        cnt = jax.ops.segment_sum(wt, e_dst, num_segments=n)
-        h = (agg + z) / (cnt[:, None] + 1) + b
-        if l < L:
-            h = jax.nn.relu(h)
-        outs.append(h)
-    return outs
-
-
-def pretrain_h(params, features, gi: GraphIndex, *, mode):
+def pretrain_h(propagate, params, features, gi: GraphIndex, *, mode):
     """h^1..h^{L-1} of every vertex over its own client's edges only, as
     each owner computes it before round 0."""
     dst = np.repeat(np.arange(gi.n), gi.deg)
     keep = (gi.part[gi.indices] == gi.part[dst]).astype(np.float32)
     e = _pad_edges(gi.indices, dst, keep, keep)
-    outs = _propagate(params, jnp.asarray(features), *e, None, None,
-                      mode=mode)
+    outs = propagate(params, jnp.asarray(features), *e, None, None,
+                     mode=mode)
     return outs[:-1]
 
 
-def client_h(params, features, gi: GraphIndex, e_src, e_dst, tables, *,
-             client, mode):
+def client_h(propagate, params, features, gi: GraphIndex, e_src, e_dst,
+             tables, *, client, mode):
     """h^1..h^{L-1} of a client's vertices over its expanded shard: at
     layer 1 only local sources (a remote vertex's features are private),
     above it the retained remote sources with their pulled rows."""
     own = gi.part == client
     e = _pad_edges(e_src, e_dst, own[e_src].astype(np.float32),
                    np.ones(len(e_src), np.float32))
-    outs = _propagate(params, jnp.asarray(features), *e, jnp.asarray(own),
-                      list(tables), mode=mode)
+    outs = propagate(params, jnp.asarray(features), *e, jnp.asarray(own),
+                     list(tables), mode=mode)
     return outs[:-1]
 
 
@@ -325,10 +284,11 @@ def eval_vertices(indptr, max_edges: int, seed: int) -> np.ndarray:
     return np.sort(perm[: max(1, k)]).astype(np.int64)
 
 
-def accuracy(params, features, labels, train_mask, gi: GraphIndex,
-             sel: np.ndarray, *, mode) -> float:
-    """Test accuracy of full-neighbourhood GraphConv over the subgraph
-    induced by ``sel``, on its vertices outside the training set."""
+def accuracy(propagate, params, features, labels, train_mask,
+             gi: GraphIndex, sel: np.ndarray, *, mode) -> float:
+    """Test accuracy of the model's full-neighbourhood ``propagate`` over
+    the subgraph induced by ``sel``, on its vertices outside the training
+    set."""
     dst = np.repeat(np.arange(gi.n), gi.deg)
     inside = np.zeros(gi.n, bool)
     inside[sel] = True
@@ -338,8 +298,8 @@ def accuracy(params, features, labels, train_mask, gi: GraphIndex,
     e = _pad_edges(pos[gi.indices[keep]], pos[dst[keep]],
                    np.ones(int(keep.sum()), np.float32),
                    np.ones(int(keep.sum()), np.float32))
-    outs = _propagate(params, jnp.asarray(features[sel]), *e, None, None,
-                      mode=mode)
+    outs = propagate(params, jnp.asarray(features[sel]), *e, None, None,
+                     mode=mode)
     pred = np.asarray(jnp.argmax(outs[-1], axis=-1))
     test = ~np.asarray(train_mask[sel], bool)
     return float((pred[test] == labels[sel][test]).mean())
@@ -395,36 +355,16 @@ def stack_batches(batches) -> dict:
     return out
 
 
-def _loss(params, b, features, tables, mode):
-    h = features[b["x"]]
-    L = len(params)
-    for l, ((w, bias), lay) in enumerate(zip(params, b["layers"]), start=1):
-        n_dst = lay["self"].shape[0]
-        e_w = lay["e_w"]
-        agg = jax.ops.segment_sum(h[lay["e_src"]] * e_w[:, None],
-                                  lay["e_dst"], num_segments=n_dst)
-        cnt = jax.ops.segment_sum(e_w, lay["e_dst"], num_segments=n_dst)
-        mixed = (agg + h[lay["self"]]) / (cnt[:, None] + 1)
-        out = _mm(mixed, w, mode) + bias
-        if l < L:
-            out = jax.nn.relu(out)
-            out = jnp.where(lay["remote"][:, None], tables[l - 1][lay["gid"]],
-                            out)
-        h = out
-    n_seed = b["labels"].shape[0]
-    logp = jax.nn.log_softmax(h[:n_seed], axis=-1)
-    nll = -jnp.take_along_axis(logp, b["labels"][:, None], axis=-1)[:, 0]
-    mask = b["mask"]
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
-
-
-@functools.partial(jax.jit, static_argnames=("mode", "lr", "b1", "b2", "eps"))
-def local_round(params, stacked, features, tables, *, mode, lr, b1, b2, eps):
-    """Adam from ``params`` over the stacked steps.  Returns per step the
-    loss, the gradient and the weights after it."""
+@functools.partial(jax.jit,
+                   static_argnames=("loss", "mode", "lr", "b1", "b2", "eps"))
+def local_round(params, stacked, features, tables, *, loss, mode, lr, b1, b2,
+                eps):
+    """Adam from ``params`` over the stacked steps of the model's
+    ``loss``.  Returns per step the loss, the gradient and the weights
+    after it."""
     def step(carry, b):
         p, mu, nu, t = carry
-        loss, g = jax.value_and_grad(_loss)(p, b, features, tables, mode)
+        value, g = jax.value_and_grad(loss)(p, b, features, tables, mode)
         t = t + 1.0
         mu = jax.tree_util.tree_map(lambda m, x: b1 * m + (1 - b1) * x, mu, g)
         nu = jax.tree_util.tree_map(lambda v, x: b2 * v + (1 - b2) * x * x,
@@ -432,7 +372,7 @@ def local_round(params, stacked, features, tables, *, mode, lr, b1, b2, eps):
         p = jax.tree_util.tree_map(
             lambda q, m, v: q - lr * (m / (1 - b1 ** t))
             / (jnp.sqrt(v / (1 - b2 ** t)) + eps), p, mu, nu)
-        return (p, mu, nu, t), (loss, g, p)
+        return (p, mu, nu, t), (value, g, p)
 
     zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
     _, (losses, grads, ps) = jax.lax.scan(
