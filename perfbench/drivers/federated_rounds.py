@@ -14,9 +14,9 @@ reference.py``) recomputes the whole set-up round from the seed.
 
 Nothing here depends on the architecture: the configuration's ``conv``
 names its model module (``yardstick/models/<conv>.py``), which gives the
-widths, the weights, the program's parameter layout, the forward pass,
-the loss and the FLOPs.  Each layer's weights travel as one tuple of
-leaves.
+trainer's model arguments, the widths, the weights, the program's
+parameter layout, the forward pass, the loss and the FLOPs.  Each
+layer's weights travel as one tuple of leaves.
 """
 
 from __future__ import annotations
@@ -148,7 +148,9 @@ def _build(cfg: dict, seed: int):
 
 def trainer(cfg: dict, seed: int):
     """The deployment's graph, its features drawn from the seed, and the
-    program's trainer over it.  Returns ``(trainer, graph)``."""
+    program's trainer over it: the model module's ``program_args`` set
+    the model's shape, the deployment's arguments are set here.
+    Returns ``(trainer, graph)``."""
     from repro.core import FederatedGNNTrainer, default_strategies
     from repro.graphs.graph import Graph
 
@@ -161,8 +163,7 @@ def trainer(cfg: dict, seed: int):
         default_strategies(retention=int(cfg["retention"]))[cfg["strategy"]],
         codec=cfg["codec"], error_feedback=bool(cfg["error_feedback"]))
     tr = FederatedGNNTrainer(
-        g, int(cfg["clients"]), strategy, conv=cfg["conv"],
-        num_layers=int(cfg["layers"]), hidden=int(cfg["hidden"]),
+        g, int(cfg["clients"]), strategy, **_model(cfg).program_args(cfg),
         fanout=int(cfg["fanout"]), batch_size=int(cfg["batch"]),
         epochs_per_round=int(cfg["epochs"]), lr=float(cfg["lr"]),
         seed=int(cfg["trainer_seed"]),

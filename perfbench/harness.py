@@ -173,7 +173,7 @@ class Tracer:
         jax.profiler.stop_trace()
         TRACE.disable()
         try:
-            events = trace.load(self._dir)
+            events = trace.load(self._dir, host_names={"bench.round"})
         finally:
             shutil.rmtree(self._dir, ignore_errors=True)
         marks = trace.host_spans(events, "bench.round")

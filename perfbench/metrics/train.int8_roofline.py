@@ -1,7 +1,8 @@
 """Roofline share of the int8 wire kernels (``kernels/quantize.py``), %:
 the HBM bytes the round's encodes and decodes need (pushed and pulled
-rows x shared layers x hidden, ``yardstick/flops.py``) over the device
-time of the kernels' programs (``jit_quantize_padded``,
+rows at each width of the model module's ``exchanged_widths``,
+``yardstick/models/<conv>.py``; bytes a row by ``yardstick/flops.py``)
+over the device time of the kernels' programs (``jit_quantize_padded``,
 ``jit_dequantize_padded``) in the trace, against the chip's HBM
 bandwidth.  Memory-bound, so bytes set the roofline."""
 

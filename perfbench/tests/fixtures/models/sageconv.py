@@ -18,6 +18,15 @@ from perfbench.yardstick.reference import mm
 SELF_TERM = True
 
 
+def program_args(cfg: dict) -> dict:
+    """The trainer's shape arguments, and ``root_weight`` (PyG's name
+    for the self term), which the program's trainer does not take: the
+    seam tests record it on its way into the trainer and take it out
+    before the real one."""
+    return {"conv": cfg["conv"], "num_layers": int(cfg["layers"]),
+            "hidden": int(cfg["hidden"]), "root_weight": SELF_TERM}
+
+
 def widths(cfg: dict) -> tuple[int, ...]:
     L = int(cfg["layers"])
     return (int(cfg["features"]),) + (int(cfg["hidden"]),) * (L - 1) \

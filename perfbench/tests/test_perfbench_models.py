@@ -1,14 +1,18 @@
 """The seam between the round driver and a configuration's model
 (``yardstick/models/<conv>.py``): the driver's work counts and the
 reference's gaps equal those the driver gave before GraphConv moved
-into its module; a second model comes in as one new module; an unknown
-``conv`` fails by name; ``calibrate.py`` runs the driver its cell's
-traffic names."""
+into its module; a second model comes in as one new module, and a new
+model's cell as new files and entries alone, its shape arguments
+reaching the trainer through the module; an unknown ``conv`` fails by
+name; ``calibrate.py`` runs the driver its cell's traffic names."""
 
 import json
 import pathlib
+import re
+import shutil
 import sys
 import types
+import uuid
 
 import pytest
 
@@ -85,11 +89,30 @@ def test_reference_gaps_equal_those_before_the_model_moved():
     assert got == GAPS
 
 
+def _recording_trainer(monkeypatch) -> list[dict]:
+    """The keyword arguments of each trainer the driver builds, recorded
+    on their way in; the fixture's ``root_weight``, which the program's
+    trainer does not take, goes no further."""
+    import repro.core
+    real = repro.core.FederatedGNNTrainer
+    got = []
+
+    def make(*args, **kwargs):
+        got.append(dict(kwargs))
+        kwargs.pop("root_weight", None)
+        return real(*args, **kwargs)
+    monkeypatch.setattr(repro.core, "FederatedGNNTrainer", make)
+    return got
+
+
 def _sageconv_verdict(monkeypatch, models_dir):
     monkeypatch.setattr(harness, "MODELS", models_dir)
+    made = _recording_trainer(monkeypatch)
     cfg = _cfg("reddit-8k", **TINY, conv="sageconv")
     tr, seen, graph, params0 = fr.build(cfg, 2 ** 33 + 5)
     assert set(tr.params[0]) == {"w_self", "w_neigh", "b"}
+    assert [m["root_weight"] for m in made] == \
+        [harness.model_of("sageconv").SELF_TERM]
     del tr
     return fr.check(seen, cfg, graph, params0, _limits())
 
@@ -109,10 +132,73 @@ def test_the_second_model_without_its_self_term_is_not_correct(
     assert not ok, shown
 
 
-def test_an_unknown_conv_fails_by_name():
+def test_a_new_model_needs_new_files_only(monkeypatch, tmp_path):
+    """A copy of the benchmark takes a model as new files alone: its
+    module, a configuration that names it, a limits file and a cell,
+    whose name is appended to the ``workloads`` lists of ``round_s`` and
+    of every per-layer metric.  The harness finds the cell and every
+    metric for it, the set-up round reads ``correct`` true, and the
+    trainer gets the module's ``program_args``, a shape argument the
+    driver does not name among them."""
+    base = tmp_path / "perfbench"
+    shutil.copytree(ROOT / "perfbench", base,
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    models = base / "yardstick" / "models"
+    shutil.copy(FIXTURE_MODELS / "sageconv.py", models / "sageconv.py")
+    cfg = {**_cfg("reddit-8k", **TINY, conv="sageconv"),
+           "name": "sage-tiny"}
+    (base / "configs" / "sage-tiny.json").write_text(json.dumps(cfg))
+    (base / "limits" / "sage-train.json").write_text(
+        json.dumps(_limits()))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "sage-tiny", "source": "test",
+                             "file": "perfbench/configs/sage-tiny.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "sage-train", "config": "sage-tiny",
+                               "traffic": "rounds", "chips": 1,
+                               "why": "test"})
+    round_s = [m for m in bench["end_to_end"] if m["name"] == "round_s"]
+    for m in round_s + bench["per_layer"]:
+        m["workloads"].append("sage-train")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    loaded = harness.load_json(tmp_path / "BENCHMARK.json")
+    files = harness.cell_files(loaded, "sage-train", base)
+    assert files["config"] == cfg and files["limits"] == _limits()
+    assert harness.driver_of(files["traffic"]) is fr
+    layer = harness.metrics_of(loaded, "sage-train", "per_layer")
+    assert [m["name"] for m in layer] == \
+        [m["name"] for m in loaded["per_layer"]]
+    assert all((base / "metrics" / f"{m['name']}.py").is_file()
+               for m in layer)
+    assert "round_s" in [m["name"] for m in
+                         harness.metrics_of(loaded, "sage-train",
+                                            "end_to_end")]
+
+    monkeypatch.setattr(harness, "MODELS", models)
+    made = _recording_trainer(monkeypatch)
+    tr, seen, graph, params0 = fr.build(files["config"], 2 ** 33 + 7)
+    del tr
+    ok, shown = fr.check(seen, files["config"], graph, params0,
+                         files["limits"])
+    assert ok, shown
+    args = harness.model_of("sageconv").program_args(files["config"])
+    assert "root_weight" in args
+    assert len(made) == 1 and made[0].items() >= args.items()
+
+
+def test_an_unknown_conv_fails_by_name(monkeypatch):
+    """A name with spaces and a fresh uuid, which no model module has;
+    the message lists every module there is, and no trainer is built."""
+    conv = f"no such model {uuid.uuid4().hex}"
+    known = sorted(p.stem for p in harness.MODELS.glob("*.py"))
+    built = []
+    monkeypatch.setattr(fr, "trainer", lambda *a: built.append(a))
     with pytest.raises(harness.NoModel,
-                       match=r"'gat'.*known: \['graphconv'\]"):
-        fr.build(_cfg("reddit-8k", **TINY, conv="gat"), 1)
+                       match=re.escape(repr(conv)) + ".*; known: "
+                       + re.escape(repr(known)) + "$"):
+        fr.build(_cfg("reddit-8k", **TINY, conv=conv), 1)
+    assert built == []
 
 
 def test_calibrate_runs_the_driver_its_traffic_names(monkeypatch, capsys):

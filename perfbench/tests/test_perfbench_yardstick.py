@@ -125,6 +125,130 @@ def test_reduce_recorded_trace():
         red["window_s"] - red["busy_s"], rel=1e-9)
 
 
+def _label_gaps_by_scan(gaps, spans, default):
+    """The oracle: every span scanned for each gap, the label of the
+    least (length, name) among those that cover the gap's middle."""
+    out = []
+    for s, e in gaps:
+        mid = 0.5 * (s + e)
+        cover = [(b - a, n) for n, a, b in spans if a <= mid <= b]
+        out.append((min(cover)[1] if cover else default, (e - s) * 1e-9))
+    return out
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 2 ** 33 + 3])
+def test_label_gaps_sweep_equals_the_scan(seed):
+    """Whole-number times on a short axis, so that lengths and names tie
+    and middles fall on spans' edges; nested spans, spans of no length,
+    and gaps beyond every span."""
+    rng = np.random.default_rng(seed)
+    spans = []
+    for _ in range(400):
+        a = float(rng.integers(0, 1000))
+        spans.append((str(rng.choice(list("abc"))), a,
+                      a + float(rng.integers(0, 40))))
+    spans += [("outer", 100.0, 900.0), ("inner", 400.0, 420.0),
+              ("inner", 400.0, 420.0), ("a", 400.0, 420.0),
+              ("b", 405.0, 415.0), ("point", 410.0, 410.0)]
+    rng.shuffle(spans)
+    starts = rng.integers(0, 1200, 600)
+    gaps = [(float(a), float(a + d))
+            for a, d in zip(starts, rng.integers(0, 21, 600))]
+    gaps += [(409.0, 411.0), (1500.0, 1600.0)]
+    got = trace.label_gaps(gaps, spans, "none")
+    assert got == _label_gaps_by_scan(gaps, spans, "none")
+    labels = [n for n, _ in got]
+    assert "none" in labels and "point" in labels and len(set(labels)) > 4
+
+
+def _device_xspace(ops, modules, lo: int) -> bytes:
+    """Two TPU planes as a serialized XSpace: ``/device:TPU:0`` with
+    ``ops`` and ``modules`` (``(name, start_ns, end_ns)``, whole ns) on
+    their lines and two events on a line the reduction does not read;
+    ``/device:TPU:1`` with one event on such a line and no ops."""
+    from jax.profiler import ProfileData
+    names = sorted({n for n, _, _ in ops + modules} | {"step 1"})
+    ids = {n: i + 1 for i, n in enumerate(names)}
+
+    def line(i, name, events):
+        evs = "".join(
+            f"events {{ metadata_id: {ids[n]} offset_ps: {(a - lo) * 1000} "
+            f"duration_ps: {(b - a) * 1000} }}" for n, a, b in events)
+        return f'lines {{ id: {i} name: "{name}" timestamp_ns: {lo} {evs} }}'
+
+    meta = "".join(f'event_metadata {{ key: {i} value {{ id: {i} name: '
+                   f'"{n}" }} }}' for n, i in ids.items())
+    steps = [("step 1", lo, lo + 10), ("step 1", lo + 20, lo + 30)]
+    text = (f'planes {{ id: 900 name: "/device:TPU:0" '
+            f'{line(1, trace.OPS_LINE, ops)} '
+            f'{line(2, trace.MODULES_LINE, modules)} '
+            f'{line(3, "Steps", steps)} {meta} }} '
+            f'planes {{ id: 901 name: "/device:TPU:1" '
+            f'{line(1, "Steps", steps[:1])} {meta} }}')
+    return ProfileData.text_proto_to_serialized_xspace(text)
+
+
+def test_filtered_load_reduces_as_the_full_load(tmp_path):
+    """A profiler trace recorded here, with ``bench.round`` marks and
+    other host spans, and two TPU planes added to it whose ops fall
+    inside the dispatch spans, so that most of the window is gaps: the
+    reduction of the load that keeps only what it reads equals that of
+    the load of every event, field for field."""
+    import jax
+    import jax.numpy as jnp
+    f = jax.jit(lambda x: jnp.tanh(x) * 2.0)
+    x = jnp.ones(64)
+    f(x).block_until_ready()
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 1
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for _ in range(3):
+            with jax.profiler.TraceAnnotation("bench.round"):
+                for _ in range(12):
+                    with jax.profiler.TraceAnnotation("client.step_inputs"):
+                        y = f(x)
+                    with jax.profiler.TraceAnnotation(
+                            "client.step_dispatch"):
+                        y.block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    full = trace.load(str(tmp_path))
+    names = ("bench.round", "client.step_inputs", "client.step_dispatch")
+    spans = [(n, a, b) for n in names for a, b in trace.host_spans(full, n)]
+    marks = trace.host_spans(full, "bench.round")
+    assert len(marks) == 3 and len(spans) == 3 + 2 * 36
+
+    rng = np.random.default_rng(5)
+    lo = int(marks[0][0]) - 1000
+    ops, modules = [], []
+    for n, a, b in spans:
+        if n != "client.step_dispatch":
+            continue
+        a, b = int(a), max(int(b), int(a) + 40)
+        cuts = np.sort(rng.integers(a, b, 6))
+        ops += [(f"%fusion.{i} = f32[64]{{0}} fusion(f32[64]{{0}} %p)",
+                 int(s), int(e)) for i, (s, e) in
+                enumerate(zip(cuts[::2], cuts[1::2]))]
+        modules.append(("jit__step(123)", int(cuts[0]), int(cuts[-1])))
+    path, = tmp_path.glob("**/*.xplane.pb")
+    path.write_bytes(path.read_bytes() + _device_xspace(ops, modules, lo))
+
+    full = trace.load(str(tmp_path))
+    kept = trace.load(str(tmp_path), host_names={"bench.round"})
+    assert len(kept) < len(full) // 2
+    assert trace.device_planes(kept) == trace.device_planes(full) == [
+        "/device:TPU:0", "/device:TPU:1"]
+    assert trace.host_spans(kept, "bench.round") == marks
+    assert trace.host_spans(kept, "client.step_inputs") == []
+    window = (marks[0][0], marks[-1][1])
+    want = trace.reduce(full, window=window, spans=spans)
+    assert trace.reduce(kept, window=window, spans=spans) == want
+    assert 0 < want["busy_s"] < want["window_s"] and want["modules"]
+    assert {n for n, _ in want["idle_gaps"]} >= {"client.step_inputs"}
+
+
 def test_harness_finds_files_it_was_not_told_about(tmp_path):
     """A new metric, cell, configuration and traffic added as files plus
     entries of BENCHMARK.json are found by name, with no code edited."""
@@ -154,13 +278,20 @@ def test_harness_finds_files_it_was_not_told_about(tmp_path):
     files = harness.cell_files(loaded, "tiny-train", base)
     assert files["config"] == TINY
     assert files["traffic"]["driver"] == "federated_rounds"
+    # the new cell reads the metrics of every cell and its own, none of
+    # those that list other cells
+    every_cell = {kind: [m["name"] for m in loaded[kind]
+                         if "workloads" not in m]
+                  for kind in ("end_to_end", "per_layer")}
     layer = harness.metrics_of(loaded, "tiny-train", "per_layer")
-    assert [m["name"] for m in layer] == ["train.made_up_s"]
-    got = harness.read_layer_metrics(layer, {"rounds": [(0, 1), (1, 2)]},
-                                     base)
+    assert [m["name"] for m in layer] == \
+        every_cell["per_layer"] + ["train.made_up_s"]
+    got = harness.read_layer_metrics(
+        layer[-1:], {"rounds": [(0, 1), (1, 2)]}, base)
     assert got == {"train.made_up_s": {"value": 5.0, "unit": "s"}}
     e2e = harness.metrics_of(loaded, "tiny-train", "end_to_end")
-    assert [m["name"] for m in e2e] == ["setup_s"]
+    assert "setup_s" in every_cell["end_to_end"]
+    assert [m["name"] for m in e2e] == every_cell["end_to_end"]
 
 
 def test_every_named_file_exists():

@@ -6,6 +6,8 @@ A model module holds all of the benchmark's code that depends on the
 architecture; ``perfbench/harness.py`` finds it by the configuration's
 ``conv`` (``yardstick/models/<conv>.py``).  Its roles:
 
+  program_args(cfg)       the keyword arguments that set the model's
+                          shape in the program's ``FederatedGNNTrainer``
   widths(cfg)             input width, then each layer's output width
   exchanged_widths(cfg)   the width of each exchanged h^1..h^{L-1}
   init(key, cfg)          seeded weights, one tuple of leaves per layer
@@ -31,6 +33,11 @@ import jax.numpy as jnp
 
 from perfbench.yardstick.flops import hop_sizes
 from perfbench.yardstick.reference import mm
+
+
+def program_args(cfg: dict) -> dict:
+    return {"conv": cfg["conv"], "num_layers": int(cfg["layers"]),
+            "hidden": int(cfg["hidden"])}
 
 
 def widths(cfg: dict) -> tuple[int, ...]:

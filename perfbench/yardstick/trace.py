@@ -10,6 +10,8 @@ the arithmetic.
 from __future__ import annotations
 
 import glob
+import heapq
+import itertools
 import os
 import re
 
@@ -19,8 +21,13 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
 
-def load(log_dir: str) -> list[tuple]:
-    """Every event of the newest ``.xplane.pb`` under ``log_dir``."""
+def load(log_dir: str, host_names=None) -> list[tuple]:
+    """Events of the newest ``.xplane.pb`` under ``log_dir``: every
+    event, or, where ``host_names`` is given, only what :func:`reduce`
+    and :func:`host_spans` read, the device planes' ``OPS_LINE`` and
+    ``MODULES_LINE`` and the host events of those names.  A device
+    plane's other lines keep their first event each, so that the plane
+    counts in :func:`device_planes` as it does in the full load."""
     from jax.profiler import ProfileData
     paths = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
@@ -28,10 +35,20 @@ def load(log_dir: str) -> list[tuple]:
         raise FileNotFoundError(f"no profiler trace under {log_dir}")
     out = []
     for plane in ProfileData.from_file(paths[-1]).planes:
+        pn = plane.name
+        device = _DEVICE_PLANE.match(pn)
         for line in plane.lines:
-            for ev in line.events:
-                out.append((plane.name, line.name, ev.name,
-                            float(ev.start_ns), float(ev.duration_ns)))
+            ln = line.name
+            if host_names is None or (device and ln in (OPS_LINE,
+                                                        MODULES_LINE)):
+                evs = line.events
+            elif device:
+                evs = itertools.islice(line.events, 1)
+            else:
+                evs = (ev for ev in line.events if ev.name in host_names)
+            for ev in evs:
+                out.append((pn, ln, ev.name, float(ev.start_ns),
+                            float(ev.duration_ns)))
     return out
 
 
@@ -95,13 +112,26 @@ def op_totals(ops, lo: float, hi: float) -> dict[str, float]:
 
 def label_gaps(gaps, spans, default: str) -> list[tuple[str, float]]:
     """Name each idle gap by the innermost (shortest) host span that
-    covers its middle; ``spans`` are ``(name, start_ns, end_ns)``."""
-    out = []
-    for s, e in gaps:
-        mid = 0.5 * (s + e)
-        cover = [(b - a, n) for n, a, b in spans if a <= mid <= b]
-        out.append((min(cover)[1] if cover else default, (e - s) * 1e-9))
-    return out
+    covers its middle, the smallest name among spans of one length;
+    ``spans`` are ``(name, start_ns, end_ns)``.  One sweep over the
+    gaps' middles in time order: a span joins a heap keyed by (length,
+    name) once it has started, and leaves it once a middle passes its
+    end."""
+    mids = sorted((0.5 * (s + e), i) for i, (s, e) in enumerate(gaps))
+    by_start = sorted((a, b, n) for n, a, b in spans)
+    labels = [default] * len(gaps)
+    heap: list[tuple] = []
+    j = 0
+    for mid, i in mids:
+        while j < len(by_start) and by_start[j][0] <= mid:
+            a, b, n = by_start[j]
+            heapq.heappush(heap, (b - a, n, b))
+            j += 1
+        while heap and heap[0][2] < mid:
+            heapq.heappop(heap)
+        if heap:
+            labels[i] = heap[0][1]
+    return [(n, (e - s) * 1e-9) for n, (s, e) in zip(labels, gaps)]
 
 
 def top(pairs, k: int = 10) -> list[list]:
