@@ -696,9 +696,9 @@ class FederatedGNNTrainer:
             t0 = time.perf_counter()
             with TRACE.span("client.train_epoch",
                             args={"client": ci, "epoch": e}):
-                for mb in batches:
-                    with TRACE.span("client.step_inputs"):
-                        batch = gnn.blocks_to_arrays(mb)
+                with TRACE.span("client.step_inputs"):
+                    steps = gnn.epoch_to_arrays(batches)
+                for batch in steps:
                     with TRACE.span("client.step_dispatch"):
                         params, opt_state, loss = self._train_step(
                             params, opt_state, batch, self.feats[ci],

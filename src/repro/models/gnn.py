@@ -7,13 +7,18 @@ overwritten from the client's pulled embedding cache instead of being
 computed (their neighbourhoods live on other clients).
 
 Everything is functional: parameters are pytrees, blocks are dicts of
-padded arrays (see :func:`blocks_to_arrays`), and the train step is a
-single jitted function per (shard, batch-size) shape signature.
+padded arrays, and the train step is a single jitted function per
+(shard, batch-size) shape signature.  Two functions build those dicts:
+:func:`blocks_to_arrays` one minibatch at a time, and
+:func:`epoch_to_arrays` a whole epoch's minibatches, packed into one
+int32 host array, moved in one host→device transfer and split on the
+device into the same per-step dicts.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Sequence
 
 import jax
@@ -21,8 +26,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.graphs.sampler import MiniBatch
+from repro.obsv.metrics import REGISTRY
 
 Params = Any
+
+#: host→device transfers made for training-step inputs, and the
+#: minibatches those transfers carried
+_TRANSFERS = REGISTRY.counter("train.step_input_transfers")
+_STEPS = REGISTRY.counter("train.step_input_steps")
 
 
 # -- parameter init ------------------------------------------------------
@@ -73,6 +84,73 @@ def blocks_to_arrays(mb: MiniBatch) -> dict:
         "seed_mask": jnp.asarray(mb.seed_mask),
         "seeds": jnp.asarray(mb.seeds, jnp.int32),
     }
+
+
+_BLOCK_KEYS = ("edge_src", "edge_dst", "edge_mask", "dst_remote_mask",
+               "dst_remote_slot", "dst_mask")
+_BATCH_KEYS = ("input_ids", "seed_mask", "seeds")
+
+
+def _step_arrays(mb: MiniBatch):
+    """``(block, key, host array)`` for every array of a step, in packing
+    order; ``block`` is None for the batch-level arrays."""
+    for i, b in enumerate(mb.blocks):
+        for k in _BLOCK_KEYS:
+            yield i, k, getattr(b, k)
+    for k in _BATCH_KEYS:
+        yield None, k, getattr(mb, k)
+
+
+def pack_epoch(batches: Sequence[MiniBatch]) -> tuple[np.ndarray, tuple]:
+    """An epoch's minibatches as one ``(len(batches), W)`` int32 array,
+    one row a step, and its layout: ``(block, key, offset, shape,
+    is_bool)`` for each array of a step.  Bool masks become 0/1 and ids
+    int32.  The layout is read from the first minibatch; a sampler pads
+    every minibatch to the same static shapes, and an array of another
+    shape raises ``ValueError``."""
+    layout, off = [], 0
+    for blk, k, a in _step_arrays(batches[0]):
+        layout.append((blk, k, off, a.shape, a.dtype == np.bool_))
+        off += a.size
+    packed = np.empty((len(batches), off), np.int32)
+    for row, mb in zip(packed, batches):
+        for (_, k, o, shape, _), (_, _, a) in zip(layout, _step_arrays(mb)):
+            if a.shape != shape:
+                raise ValueError(f"{k}: shape {a.shape} in an epoch laid "
+                                 f"out for {shape}")
+            row[o: o + a.size] = a.reshape(-1)
+    return packed, tuple(layout)
+
+
+@functools.partial(jax.jit, static_argnames=("layout",))
+def _split_epoch(packed, *, layout):
+    """The rows of :func:`pack_epoch` as per-step batch dicts, in one
+    program."""
+    n_blocks = 1 + max(blk for blk, *_ in layout if blk is not None)
+    steps = []
+    for i in range(packed.shape[0]):
+        blocks = [{} for _ in range(n_blocks)]
+        batch = {"blocks": blocks}
+        for blk, k, off, shape, is_bool in layout:
+            x = packed[i, off: off + math.prod(shape)].reshape(shape)
+            (batch if blk is None else blocks[blk])[k] = \
+                x != 0 if is_bool else x
+        steps.append(batch)
+    return steps
+
+
+def epoch_to_arrays(batches: Sequence[MiniBatch]) -> list[dict]:
+    """Every step's batch dict of an epoch, each equal to
+    ``blocks_to_arrays(mb)`` in keys, shapes, dtypes and values, moved
+    to the device in one transfer instead of one for each array of each
+    step."""
+    if not batches:
+        return []
+    packed, layout = pack_epoch(batches)
+    steps = _split_epoch(jax.device_put(packed), layout=layout)
+    _TRANSFERS.inc()
+    _STEPS.inc(len(batches))
+    return steps
 
 
 def _segment_mean(vals, seg_ids, mask, num_segments):

@@ -98,6 +98,21 @@ def test_gnn_train_step_compiles(one_chip):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
+def test_epoch_split_compiles(one_chip):
+    """The device split of one packed client epoch (batch 64, fanout 5,
+    3 layers), the program that hands the step its inputs."""
+    from repro.core import FederatedGNNTrainer, default_strategies
+    from repro.graphs import make_graph
+    from repro.models import gnn
+
+    tr = FederatedGNNTrainer(make_graph("reddit", scale=0.05, seed=0), 2,
+                             default_strategies()["OP"], seed=0)
+    packed, layout = gnn.pack_epoch(list(tr.samplers[0].epoch()))
+    compiled = gnn._split_epoch.lower(
+        _spec(one_chip, packed.shape, jnp.int32), layout=layout).compile()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
 def test_auto_dispatch_reaches_only_compiling_kernels(monkeypatch):
     """On a TPU backend, ``use_pallas="auto"`` picks the Pallas body only
     for the kernels compiled above; ``use_pallas=True`` still forces it."""
